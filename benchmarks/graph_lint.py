@@ -102,6 +102,8 @@ def _lint_one(name, module, cli, env_extra):
         [os.path.abspath("src")] + env.get("PYTHONPATH", "").split(os.pathsep)
     )
     env.update(env_extra)
+    # a CPU lint by design: the parent may hold the accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     t0 = time.time()
     out = subprocess.run(
         [sys.executable, "-m", module, *cli.split(), "--json"],

@@ -48,7 +48,7 @@ _SUBPROC = textwrap.dedent("""
     from repro.configs.base import ModelConfig, attn
     from repro.core import CompressorConfig
     from repro.data.synthetic import LMDataConfig, lm_batch
-    from repro.launch.mesh import make_mesh, use_mesh
+    from repro.launch.mesh import make_mesh
     from repro.train.optimizer import sgd
     from repro.train.runtime import build_sharded_step, sharded_init
     from repro.train.step import make_model_compressor
@@ -72,7 +72,7 @@ _SUBPROC = textwrap.dedent("""
 
     MODES = {"eager": None, "lazy_gate": "gate", "lazy_elide": "elide"}
     best, colls = {}, {}
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         built = {}
         for name, mode in MODES.items():
             comp = make_model_compressor(cfg, comp_cfg(mode))
@@ -111,6 +111,8 @@ def bench(quick: bool = False) -> tuple[list[tuple[str, float, str]], dict]:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.abspath("src")] + env.get("PYTHONPATH", "").split(os.pathsep))
+    # a CPU simulation by design: the parent may hold the accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", src], env=env,
                          capture_output=True, text=True, timeout=1800)
     if out.returncode != 0:

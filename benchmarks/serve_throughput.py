@@ -23,7 +23,7 @@ can recover from the stored cache, not a full attack; lower SSIM at q4
 means the cache itself retains measurably less invertible signal.
 
 Timing note: quantized variants time the ``jnp_ref`` codec backend — the
-Pallas kernels run in interpret mode off-TPU (a semantics emulator, not a
+Pallas kernels run in interpret mode on the CPU (a semantics emulator, not a
 CPU fast path) and are asserted byte-identical to jnp_ref in the test
 suite, so bytes/accounting here transfer to the TPU path unchanged.
 

@@ -36,7 +36,7 @@ import jax
 from repro.configs.base import ModelConfig, attn
 from repro.core import CompressorConfig
 from repro.data.synthetic import LMDataConfig, lm_batch
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.train.optimizer import sgd
 from repro.train.runtime import (AsyncRunner, RuntimeConfig,
                                  build_sharded_step, sharded_init)
@@ -105,7 +105,7 @@ def bench(quick: bool = False) -> tuple[list[tuple[str, float, str]], dict]:
 
     rows: list[tuple[str, float, str]] = []
     best: dict[str, dict] = {}
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jstep, st_sh, _, _ = build_sharded_step(
             cfg, mesh, comp, opt, sample_batch=batch_fn(0), remat_scan=False)
         # compile outside the timed region (both modes share the executable)

@@ -14,7 +14,7 @@ import jax
 from repro.configs import get_config
 from repro.core import CompressorConfig
 from repro.data.synthetic import LMDataConfig, lm_batch
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.train.optimizer import sgd
 from repro.train.step import (build_train_step, init_train_state,
                               make_model_compressor, n_dp_of)
@@ -31,7 +31,7 @@ def main():
                                      remat_scan=False)
 
     data = LMDataConfig(vocab_size=cfg.vocab_size, seq_len=64, batch=8)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = init_train_state(cfg, jax.random.PRNGKey(0), optimizer,
                                  compressor, n_dp_of(mesh))
         n_params = sum(x.size for x in jax.tree.leaves(state["params"]))

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.model import init_params
 from repro.serving.engine import (build_generate_fn, build_prefill_step,
                                   greedy_sample)
@@ -38,7 +38,7 @@ def main():
     batch, prompt_len, gen = 4, 32, 24
     max_seq = prompt_len + gen
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = init_params(cfg, jax.random.PRNGKey(0))
         tokens = jax.random.randint(jax.random.PRNGKey(1),
                                     (batch, prompt_len), 0, cfg.vocab_size)

@@ -19,7 +19,7 @@ from repro.checkpoint.io import restore
 from repro.configs.base import ModelConfig, attn
 from repro.core import CompressorConfig
 from repro.data.synthetic import LMDataConfig, lm_batch
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.train.optimizer import sgd
 from repro.train.step import (build_train_step, init_train_state,
                               make_model_compressor, n_dp_of)
@@ -52,7 +52,7 @@ def main():
     data = LMDataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq,
                         batch=args.batch)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = init_train_state(cfg, jax.random.PRNGKey(0), opt, comp,
                                  n_dp_of(mesh))
         n = sum(x.size for x in jax.tree.leaves(state["params"]))

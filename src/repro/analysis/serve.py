@@ -115,7 +115,6 @@ def lint_serve_step(
     from repro.analysis.hlo import parse_module, parse_type
     from repro.analysis.inventory import hlo_inventory
     from repro.analysis.rules import Finding, LintReport, RuleResult
-    from repro.launch.mesh import use_mesh
     from repro.models.model import init_params
     from repro.serving.engine import (
         build_decode_step,
@@ -137,7 +136,7 @@ def lint_serve_step(
         cfg, mesh, batch, cache_dtype=cache_dtype, qcfg=qcfg
     )
     decode = build_decode_step(cfg)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(
             decode,
             in_shardings=(p_sh, c_sh, t_sh, None),

@@ -27,10 +27,9 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.configs import INPUT_SHAPES
 from repro.configs.base import ModelConfig
 from repro.core import CompressorConfig
-from repro.core.comm import AxisComm, shard_map
+from repro.core.comm import AxisComm
 from repro.core.compressors import GradCompressor
 from repro.launch.inputs import input_specs
-from repro.launch.mesh import use_mesh
 from repro.train.optimizer import sgd
 from repro.train.step import (
     build_train_step,
@@ -69,7 +68,7 @@ def trace_sync_jaxpr(
         out, new_state, _rec = comp.sync(grads, st, AxisComm((axis_name,)))
         return out, new_state
 
-    f = shard_map(
+    f = jax.shard_map(
         worker,
         mesh=mesh,
         in_specs=(P(), P()),
@@ -110,7 +109,7 @@ def trace_step_jaxpr(
     compressor, step_fn, _, _, state_abs, batch_abs = _step_pieces(
         cfg, comp_cfg, mesh, shape_name
     )
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jaxpr = jax.make_jaxpr(step_fn)(state_abs, batch_abs)
     return jaxpr, compressor
 
@@ -128,7 +127,7 @@ def compile_step_hlo(
     compressor, step_fn, state_sh, batch_sh, state_abs, batch_abs = _step_pieces(
         cfg, comp_cfg, mesh, shape_name
     )
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         st_sh = state_sh(state_abs)
         jitted = jax.jit(
             step_fn,

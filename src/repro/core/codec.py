@@ -16,8 +16,8 @@ against the codec's dataclass fields. Registered codecs:
     factors, TopK's dense-simulated sparse payload);
   * ``log`` :class:`LogQuantCodec` — the paper's Eq. 5/6 log-quantizer,
     with two backends: ``jnp_ref`` (pure jnp, default) and ``pallas`` (the
-    fused TPU kernels in ``repro.kernels.log_quant``, interpret-mode
-    off-TPU), validated bit-for-bit against each other;
+    fused TPU kernels in ``repro.kernels.log_quant``, interpret-mode on
+    the CPU), validated bit-for-bit against each other;
   * ``qsgd`` :class:`QSGDCodec`     — stochastic uniform quantization
     (Alistarh et al. 2017), the canonical baseline the paper cites;
   * ``dlog`` :class:`DitheredLogQuantCodec` — the log grid with unbiased
@@ -54,8 +54,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.comm import AxisComm, CommRecord
-from repro.core.quantization import (LogQuantConfig, code_dtype, log_compress,
-                                     log_expand, quantize)
+from repro.core.quantization import (LogQuantConfig, code_dtype, dequantize,
+                                     log_compress, log_expand, quantize)
 from repro.core.wire import SymmetricWire, as_wire
 
 __all__ = [
@@ -77,10 +77,6 @@ __all__ = [
 ]
 
 CODEC_BACKENDS = ("jnp_ref", "pallas")
-
-
-def _pallas_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # --------------------------------------------------------------------------
@@ -289,7 +285,7 @@ class Float32Codec(WireCodec):
 class LogQuantCodec(WireCodec):
     """Paper Eq. 5/6 log-quantizer. ``backend='pallas'`` routes the
     quantize/dequantize math and the b<=4 nibble pack through the Pallas
-    kernels (interpret mode off-TPU); both backends emit identical bytes."""
+    kernels (interpret mode on the CPU); both backends emit identical bytes."""
 
     bits: int = 8
     alpha: float = 10.0
@@ -310,8 +306,7 @@ class LogQuantCodec(WireCodec):
         if self.backend == "pallas":
             from repro.kernels.log_quant import log_quantize_pallas
             return log_quantize_pallas(x, jnp.float32(1.0), bits=self.bits,
-                                       alpha=self.alpha,
-                                       interpret=_pallas_interpret())
+                                       alpha=self.alpha)
         return quantize(x, self._cfg)
 
     def encode(self, x, *, key=None):
@@ -322,8 +317,7 @@ class LogQuantCodec(WireCodec):
             # two kernel launches (bytes identical to the jnp packer)
             from repro.kernels.log_quant import log_quantize_pack_pallas
             return log_quantize_pack_pallas(x, jnp.float32(1.0),
-                                            bits=self.bits, alpha=self.alpha,
-                                            interpret=_pallas_interpret())
+                                            bits=self.bits, alpha=self.alpha)
         c = self.codes(x)
         if self.bits <= 4:
             return pack_nibbles(c)
@@ -338,9 +332,8 @@ class LogQuantCodec(WireCodec):
         if self.backend == "pallas":
             from repro.kernels.log_quant import log_dequantize_pallas
             return log_dequantize_pallas(codes, jnp.float32(1.0), bits=self.bits,
-                                         alpha=self.alpha,
-                                         interpret=_pallas_interpret())
-        return log_expand(codes.astype(jnp.float32) / self._cfg.levels, self.alpha)
+                                         alpha=self.alpha)
+        return dequantize(codes, self._cfg)
 
     def wire_bits(self, numel):
         return packed_wire_bits(numel, self.bits)
@@ -383,7 +376,7 @@ class QSGDCodec(WireCodec):
         if self.bits <= 4:
             if self.backend == "pallas":
                 from repro.kernels.log_quant import pack_nibbles_pallas
-                return pack_nibbles_pallas(c, interpret=_pallas_interpret())
+                return pack_nibbles_pallas(c)
             return pack_nibbles(c)
         return c.reshape(-1)
 

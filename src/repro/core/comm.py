@@ -19,32 +19,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-__all__ = ["AxisComm", "CommRecord", "shard_map"]
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-              check_vma: bool = False):
-    """Version-tolerant ``jax.shard_map``.
-
-    jax >= 0.6 exposes ``jax.shard_map(f, mesh=..., axis_names=...,
-    check_vma=...)`` with partially-manual axes: names outside
-    ``axis_names`` stay auto (XLA partitions the tensor-parallel math).
-    Older releases route to ``jax.experimental.shard_map.shard_map``,
-    where partial-auto (`auto=`) exists but its SPMD partitioner is not
-    reliable (hard ``IsManualSubgroup`` CHECK failures on CPU) — so there
-    we run ALL axes manual: tensors spec'd ``P()`` replicate over the
-    would-be-auto axes and compute redundantly. Numerically identical,
-    no TP sharding speedup; acceptable for tests/CPU simulation.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+__all__ = ["AxisComm", "CommRecord"]
 
 
 @dataclasses.dataclass

@@ -87,7 +87,7 @@ class CompressorConfig:
     # topology); the old kwarg/attribute still works but warns.
     wire_accounting: str = "allgather_codes"
     # wire-codec backend: 'jnp_ref' (pure jnp) or 'pallas' (TPU kernels,
-    # interpret-mode off-TPU) — see repro.core.codec
+    # interpret-mode on the CPU) — see repro.core.codec
     quant_backend: str = "jnp_ref"
     # wire codec override for the log-quant family: None -> 'log'
     # deterministic (or 'dlog' when dp_epsilon > 0), or any registered

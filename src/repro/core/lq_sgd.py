@@ -12,7 +12,7 @@ Eq. 5/6):
            | mean(dequant(codes))                  ["dequant_then_mean"]
 
 ``cfg.quant_backend`` selects the codec backend: ``jnp_ref`` (pure jnp) or
-``pallas`` (the fused TPU kernels, interpret-mode off-TPU). b<=4 codes are
+``pallas`` (the fused TPU kernels, interpret-mode on the CPU). b<=4 codes are
 nibble-packed two-per-int8, so the gathered arrays really are b/8 of the
 int8 bytes — wire accounting equals actual array bytes.
 

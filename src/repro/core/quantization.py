@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -82,18 +83,26 @@ def log_expand(q: jax.Array, alpha: float) -> jax.Array:
     return jnp.sign(q) * jnp.expm1(jnp.abs(q) * jnp.log1p(alpha)) / alpha
 
 
+# quantize/dequantize fold Eq. 5/6's constants into ONE host-computed factor
+# each and write ``exp(.) - 1`` for ``expm1``: the Pallas kernels in
+# ``repro.kernels.log_quant`` compute exactly these forms (Mosaic lowers no
+# ``expm1``), and with a single constant there is no chain for XLA to
+# refold, so the two backends agree bit for bit on the TPU too.
+
+
 def quantize(x: jax.Array, cfg: LogQuantConfig) -> jax.Array:
     """Normalized input (|x| <= 1) -> signed integer codes in [-L, L]."""
     lv = cfg.levels
-    q = log_compress(x.astype(jnp.float32), cfg.alpha)  # in [-1, 1]
-    codes = jnp.round(q * lv)
-    return jnp.clip(codes, -lv, lv).astype(code_dtype(cfg.bits))
+    x = x.astype(jnp.float32)
+    q = jnp.sign(x) * jnp.log1p(cfg.alpha * jnp.abs(x)) * (lv / math.log1p(cfg.alpha))
+    return jnp.clip(jnp.round(q), -lv, lv).astype(code_dtype(cfg.bits))
 
 
 def dequantize(codes: jax.Array, cfg: LogQuantConfig) -> jax.Array:
-    """Signed integer codes -> normalized float values (|x| <= 1)."""
-    q = codes.astype(jnp.float32) / cfg.levels
-    return log_expand(q, cfg.alpha)
+    """Signed (or averaged) codes -> normalized float values (|x| <= 1)."""
+    c = codes.astype(jnp.float32)
+    rate = math.log1p(cfg.alpha) / cfg.levels
+    return jnp.sign(c) * (jnp.exp(jnp.abs(c) * rate) - 1.0) / cfg.alpha
 
 
 def quantize_with_scale(x: jax.Array, cfg: LogQuantConfig, scale: jax.Array | None = None):

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.kernels.backend import pallas_interpret
+
 __all__ = ["flash_attention_pallas"]
 
 _NEG = -1e30
@@ -76,7 +78,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, window: int | None = None,
                            sm_scale: float | None = None,
                            block_q: int = 256, block_k: int = 256,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool | None = None) -> jax.Array:
     """q: (B, Hq, S, D); k, v: (B, Hkv, S, D), Hq % Hkv == 0 -> (B, Hq, S, D).
 
     Sequence is padded to block multiples; causal masking keeps padded keys
@@ -120,11 +122,9 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),   # running max m
             pltpu.VMEM((bq, 1), jnp.float32),   # running denom l
         ],
-        # jax >= 0.5 renamed TPUCompilerParams -> CompilerParams
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(qp, kp, vp)
     return out[:, :, :s, :]

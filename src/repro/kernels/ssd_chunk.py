@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import pallas_interpret
+
 __all__ = ["ssd_chunk_pallas"]
 
 
@@ -43,7 +45,8 @@ def _ssd_chunk_kernel(x_ref, acum_ref, b_ref, c_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ssd_chunk_pallas(x: jax.Array, a_cum: jax.Array, bm: jax.Array,
-                     cm: jax.Array, *, interpret: bool = True) -> jax.Array:
+                     cm: jax.Array, *,
+                     interpret: bool | None = None) -> jax.Array:
     """Intra-chunk SSD term.
 
     x     (B, H, NC, Q, P)  dt-weighted inputs, chunked
@@ -68,6 +71,6 @@ def ssd_chunk_pallas(x: jax.Array, a_cum: jax.Array, bm: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, 1, q, p), lambda i, c: (i, c, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, nc, q, p), jnp.float32),
-        interpret=interpret,
+        interpret=pallas_interpret() if interpret is None else interpret,
     )(resh(x), ac2, resh(bm), resh(cm))
     return out.reshape(b, h, nc, q, p)

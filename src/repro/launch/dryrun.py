@@ -29,7 +29,7 @@ from repro.configs import INPUT_SHAPES, get_config, list_archs, shape_supported
 from repro.configs.base import ModelConfig
 from repro.core import CompressorConfig
 from repro.launch.inputs import input_specs
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models.model import init_caches, init_params
 from repro.roofline import hw
 from repro.roofline.analysis import roofline_terms
@@ -84,7 +84,7 @@ def lower_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     comp_cfg = comp_cfg or CompressorConfig(name="lq_sgd", rank=1, bits=8)
     t0 = time.time()
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.mode == "train":
             compressor = make_model_compressor(cfg, comp_cfg)
             opt = sgd(1e-2)
@@ -143,8 +143,6 @@ def lower_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     t_compile = time.time() - t0
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # newer jax: one dict per executable module
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     if dump_hlo:
         with open(dump_hlo, "w") as f:

@@ -4,15 +4,7 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "make_mesh", "use_mesh"]
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh``: ``jax.set_mesh`` on jax >= 0.6,
-    the Mesh object's own context on older releases."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+__all__ = ["make_production_mesh", "make_mesh"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -23,8 +15,5 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
-    # older jax (< 0.5): meshes are Auto-typed implicitly
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(shape))

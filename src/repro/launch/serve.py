@@ -23,7 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, list_archs
-from repro.launch.mesh import make_mesh, make_production_mesh, use_mesh
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.models.model import init_params
 from repro.models.multimodal import codec_tokens_stub, conditioning_stub, vq_tokens_stub
 from repro.serving.engine import (build_generate_fn, build_prefill_step,
@@ -58,6 +59,7 @@ def main() -> None:
                     choices=("fixed", "continuous"))
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.production_mesh:
         mesh = make_production_mesh()
@@ -82,7 +84,7 @@ def main() -> None:
                                     cfg.vocab_size)
     cond = (conditioning_stub(key, args.batch, cfg) if cfg.cond_len else None)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = init_params(cfg, jax.random.PRNGKey(1))
 
         if args.scheduler == "continuous":

@@ -30,7 +30,8 @@ from repro.checkpoint.io import peek_step, restore as ckpt_restore
 from repro.configs import get_config, list_archs
 from repro.core import CompressorConfig
 from repro.data.synthetic import LMDataConfig, lm_batch
-from repro.launch.mesh import make_mesh, make_production_mesh, use_mesh
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.train.optimizer import make_optimizer
 from repro.train.runtime import (AsyncRunner, RuntimeConfig,
                                  build_sharded_step, run_schedule,
@@ -39,7 +40,9 @@ from repro.train.step import make_model_compressor
 from repro.train.trainer import Trainer
 
 
-def main() -> None:
+def run(argv: list[str] | None = None) -> list[dict[str, float]]:
+    """Parse ``argv`` (default: the command line), train, and return the
+    runner's ``history``: one dict of metrics per logged step."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list_archs())
     ap.add_argument("--smoke", action="store_true",
@@ -148,7 +151,7 @@ def main() -> None:
                     help="restore from --ckpt-path and continue; schedule "
                          "phases already completed are skipped (their "
                          "warm-Q truncations are not re-applied)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.production_mesh:
         mesh = make_production_mesh(multi_pod=args.multi_pod)
@@ -221,7 +224,7 @@ def main() -> None:
                 ).astype(jnp.dtype(cfg.dtype))
         return b
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         def build(comp):
             return build_sharded_step(cfg, mesh, comp, optimizer,
                                       sample_batch=batch_fn(0),
@@ -281,6 +284,12 @@ def main() -> None:
         # resume — see repro.train.runtime.run_schedule
         run_schedule(runner, compressor, state, total_steps=args.steps,
                      rebuild=rebuild, initial=comp0)
+    return runner.history
+
+
+def main() -> None:
+    use_compile_cache()
+    run()
 
 
 if __name__ == "__main__":

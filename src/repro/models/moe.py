@@ -19,6 +19,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models.common import KeyGen, act_fn, dense_init
@@ -108,28 +109,24 @@ def _moe_constraint(x: jax.Array, cfg: ModelConfig, *, batch_dim: int | None = N
     are Manual (shapes already local) and only `model` is constrained."""
     if not cfg.moe_shard_hints:
         return x
-    try:
-        from jax.sharding import PartitionSpec as P
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or not mesh.shape:
-            return x
-        shape = dict(mesh.shape)
-        auto = {n for n, t in zip(mesh.axis_names, mesh.axis_types)
-                if t == jax.sharding.AxisType.Auto}
-        spec = [None] * x.ndim
-        if (expert_dim is not None and "model" in auto
-                and cfg.n_experts % shape.get("model", 1) == 0):
-            spec[expert_dim] = "model"
-        if batch_dim is not None:
-            dp = tuple(a for a in ("pod", "data")
-                       if a in auto and x.shape[batch_dim] % shape[a] == 0)
-            if dp:
-                spec[batch_dim] = dp if len(dp) > 1 else dp[0]
-        if all(v is None for v in spec):
-            return x
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except Exception:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.shape:  # no mesh in scope
         return x
+    shape = dict(mesh.shape)
+    auto = {n for n, t in zip(mesh.axis_names, mesh.axis_types)
+            if t == jax.sharding.AxisType.Auto}
+    spec = [None] * x.ndim
+    if (expert_dim is not None and "model" in auto
+            and cfg.n_experts % shape.get("model", 1) == 0):
+        spec[expert_dim] = "model"
+    if batch_dim is not None:
+        dp = tuple(a for a in ("pod", "data")
+                   if a in auto and x.shape[batch_dim] % shape[a] == 0)
+        if dp:
+            spec[batch_dim] = dp if len(dp) > 1 else dp[0]
+    if all(v is None for v in spec):
+        return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
 def _dispatch_tables(p: Params, xf: jax.Array, cfg: ModelConfig, cap: int):

@@ -71,10 +71,6 @@ __all__ = [
 QUANT_CACHE_LEAVES = ("'k'", "'v'", "'ckv'", "'krope'")
 
 
-def _pallas_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class QuantKV:
@@ -145,7 +141,7 @@ def dequantize_kv(q: QuantKV, dtype=jnp.float32) -> jax.Array:
         from repro.kernels.log_quant import log_dequantize_rows_pallas
         flat = log_dequantize_rows_pallas(
             q.codes.reshape(-1, nb), q.scale.reshape(-1, 1).astype(jnp.float32),
-            bits=q.bits, alpha=q.alpha, interpret=_pallas_interpret())
+            bits=q.bits, alpha=q.alpha)
         return flat[:, :q.d].reshape(lead + (q.d,)).astype(dtype)
     codec = _codec(q.bits, q.alpha, "jnp_ref")
     vals = codec.expand(codec.decode(q.codes.reshape(-1), q.codes.size

@@ -260,13 +260,15 @@ class AsyncRunner:
         self.host_s = 0.0   # main-thread blocked time (cf. Trainer.host_s)
         self._t0: float | None = None
 
-    def _emit(self, step: int, metrics: Any, t_log: float) -> None:
+    def _emit(self, step: int, metrics: Any) -> None:
         th = time.time()
         # ONE transfer for the whole metric dict — per-metric float() pays
         # a separate host sync per value (the sync loop's behavior)
         m = {k: float(v) for k, v in jax.device_get(metrics).items()}
         m["step"] = step
-        m["wall_s"] = round(t_log - self._t0, 2)
+        # as in Trainer: when the step's metrics reached the host, so the
+        # step had finished (at log_every=1 that is its completion time)
+        m["wall_s"] = round(time.time() - self._t0, 2)
         self.history.append(m)
         if self.cfg.verbose:
             msg = " ".join(f"{k}={v:.4f}" for k, v in m.items()
@@ -285,7 +287,7 @@ class AsyncRunner:
         saver = AsyncCheckpointer(cfg.ckpt_path) if cfg.ckpt_every else None
         pf = _Prefetcher(self.batch_fn, start_step, cfg.steps,
                          depth=cfg.prefetch)
-        pending: list[tuple[int, Any, float]] = []
+        pending: list[tuple[int, Any]] = []
         # the jitted step makes many brief GIL round-trips while it blocks;
         # with background threads active, each re-acquire can wait a full
         # interpreter switch interval (5ms default) — shrink it for the
@@ -300,7 +302,7 @@ class AsyncRunner:
                 state, metrics = self.step_fn(state, batch)
                 if (step % cfg.log_every == 0
                         or step == cfg.steps - 1):
-                    pending.append((step, metrics, time.time()))
+                    pending.append((step, metrics))
                 # fetch only the PREVIOUS interval's metrics: this step is
                 # already queued on the device, so the float() sync below
                 # overlaps compute instead of stalling dispatch

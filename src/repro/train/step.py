@@ -23,7 +23,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core import AxisComm, CompressorConfig, make_compressor
-from repro.core.comm import shard_map
 from repro.core.compressors import GradCompressor
 from repro.core.lazy import STALE_NS
 from repro.launch.sharding import assert_replicated, param_specs
@@ -176,10 +175,10 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, compressor: GradCompressor,
         specs_state["comp"] = jax.tree.map(lambda _: P(dp), state["comp"])
         specs_batch = jax.tree.map(lambda _: P(dp), batch)
         metric_specs = {k: rep for k in _metric_keys(cfg)}
-        return shard_map(per_dp, mesh=mesh,
-                         in_specs=(specs_state, specs_batch),
-                         out_specs=(specs_state, metric_specs),
-                         axis_names=set(dp), check_vma=False)(state, batch)
+        return jax.shard_map(per_dp, mesh=mesh,
+                             in_specs=(specs_state, specs_batch),
+                             out_specs=(specs_state, metric_specs),
+                             axis_names=set(dp), check_vma=False)(state, batch)
 
     # ---- NamedShardings for jit / lower ----------------------------------
     abstract_params = jax.eval_shape(lambda k: init_params(cfg, k),

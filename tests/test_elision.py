@@ -314,7 +314,7 @@ _ELISION_SUBPROC = textwrap.dedent("""
     from repro.configs.base import ModelConfig, attn
     from repro.core import CompressorConfig
     from repro.data.synthetic import LMDataConfig, lm_batch
-    from repro.launch.mesh import make_mesh, use_mesh
+    from repro.launch.mesh import make_mesh
     from repro.train.optimizer import sgd
     from repro.train.runtime import (AsyncRunner, RuntimeConfig,
                                      build_sharded_step, sharded_init)
@@ -332,7 +332,7 @@ _ELISION_SUBPROC = textwrap.dedent("""
     data = LMDataConfig(vocab_size=128, seq_len=32, batch=8)
     bf = lambda i: lm_batch(data, i)
     out = {}
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jstep, st_sh, b_sh, st_abs = build_sharded_step(
             cfg, mesh, comp, opt, sample_batch=bf(0), remat_scan=False)
         state = sharded_init(cfg, jax.random.PRNGKey(0), opt, comp, mesh,

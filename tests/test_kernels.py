@@ -79,6 +79,22 @@ def test_log_quant_property(n, bits, alpha, seed):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("platform, interpret", [("cpu", True), ("tpu", False)])
+def test_pallas_interpret_follows_the_backend(monkeypatch, platform, interpret):
+    from repro.kernels import backend
+    monkeypatch.setattr(backend.jax, "default_backend", lambda: platform)
+    assert backend.pallas_interpret() is interpret
+
+
+def test_pallas_interpret_refuses_other_backends(monkeypatch):
+    """A GPU has no compiled kernels here; interpreting there would hide
+    which device ran the work."""
+    from repro.kernels import backend
+    monkeypatch.setattr(backend.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        backend.pallas_interpret()
+
+
 # ----------------------------------------------------------- flash attention
 @pytest.mark.parametrize("b,hq,hkv,s,d", [
     (1, 2, 2, 64, 32),     # MHA

@@ -46,21 +46,14 @@ def test_roofline_terms_dominance():
 
 def test_cost_analysis_is_per_device():
     """The empirical fact the roofline math relies on."""
-    import contextlib
-    if hasattr(jax.sharding, "AxisType") and hasattr(jax, "set_mesh"):
-        mesh = jax.make_mesh((1,), ("model",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-        ctx = jax.set_mesh(mesh)
-    else:  # older jax: a size-1 mesh changes nothing about the analysis
-        ctx = contextlib.nullcontext()
-    with ctx:
+    mesh = jax.make_mesh((1,), ("model",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
+    with jax.set_mesh(mesh):
         m, k, n = 256, 256, 256
         low = jax.jit(lambda a, b: a @ b).lower(
             jax.ShapeDtypeStruct((m, k), jnp.float32),
             jax.ShapeDtypeStruct((k, n), jnp.float32))
         cost = low.compile().cost_analysis()
-        if isinstance(cost, list):  # older jax: one entry per computation
-            cost = cost[0]
         assert abs(cost["flops"] - 2 * m * k * n) / (2 * m * k * n) < 0.01
 
 

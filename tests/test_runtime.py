@@ -25,7 +25,7 @@ from repro.checkpoint.io import AsyncCheckpointer, restore as ckpt_restore
 from repro.configs.base import ModelConfig, attn
 from repro.core import CompressorConfig
 from repro.data.synthetic import LMDataConfig, lm_batch
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.train.optimizer import sgd
 from repro.train.runtime import (AsyncRunner, RuntimeConfig, _SnapshotPacker,
                                  build_sharded_step, run_schedule,
@@ -61,7 +61,7 @@ def _params_equal(a, b):
 # ------------------------------------------------------- sync == async ----
 def test_async_runner_matches_trainer_bit_for_bit():
     mesh, cfg, comp, opt, bf = _setup()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jstep, st_sh, _, _ = build_sharded_step(cfg, mesh, comp, opt,
                                                 sample_batch=bf(0),
                                                 remat_scan=False)
@@ -87,7 +87,7 @@ def test_async_runner_matches_trainer_bit_for_bit():
 # ------------------------------------------------ gradient accumulation ----
 def test_microbatch_k1_equals_no_accumulation():
     mesh, cfg, comp, opt, bf = _setup()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         finals = {}
         for k in (None, 1, 4):
             if k is None:  # the pre-runtime path: un-sharded jit, no accum
@@ -116,7 +116,7 @@ def test_microbatch_k1_equals_no_accumulation():
 
 def test_microbatch_rejects_indivisible_batch():
     mesh, cfg, comp, opt, bf = _setup(batch=6)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jstep, _, _, _ = build_sharded_step(cfg, mesh, comp, opt,
                                             sample_batch=bf(0), microbatch=4,
                                             remat_scan=False)
@@ -209,7 +209,7 @@ def test_run_schedule_resume_mid_decay(tmp_path):
     still fire."""
     mesh, cfg, comp, opt, bf = _decay_setup()
     ck = str(tmp_path / "s.ckpt")
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         def build(c):
             return build_sharded_step(cfg, mesh, c, opt, sample_batch=bf(0),
                                       remat_scan=False)
@@ -268,7 +268,7 @@ def test_resume_checkpoint_saved_exactly_on_boundary(tmp_path):
     and run_schedule must then apply the boundary adaptation once."""
     mesh, cfg, comp, opt, bf = _decay_setup()
     ck = str(tmp_path / "s.ckpt")
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         def build(c):
             return build_sharded_step(cfg, mesh, c, opt, sample_batch=bf(0),
                                       remat_scan=False)
@@ -316,7 +316,7 @@ def test_run_schedule_threads_one_runner_history(tmp_path):
     """Regression: the launcher built a fresh Trainer per schedule phase,
     so history was discarded and wall_s restarted at each boundary."""
     mesh, cfg, comp, opt, bf = _decay_setup()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         def build(c):
             return build_sharded_step(cfg, mesh, c, opt, sample_batch=bf(0),
                                       remat_scan=False)
@@ -339,7 +339,7 @@ def test_run_schedule_threads_one_runner_history(tmp_path):
 def test_run_schedule_plain_compressor_passthrough():
     """No schedule attr (dedicated compressors): one phase, no rebuild."""
     mesh, cfg, comp, opt, bf = _setup()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jstep, st_sh, _, _ = build_sharded_step(cfg, mesh, comp, opt,
                                                 sample_batch=bf(0),
                                                 remat_scan=False)
@@ -363,13 +363,17 @@ _SHARDING_SUBPROC = textwrap.dedent("""
     from repro.core import CompressorConfig
     from repro.data.synthetic import LMDataConfig, lm_batch
     from repro.checkpoint.io import restore as ckpt_restore
-    from repro.launch.mesh import make_mesh, use_mesh
+    from repro.launch.mesh import make_mesh
     from repro.train.optimizer import sgd
     from repro.train.runtime import (AsyncRunner, RuntimeConfig,
                                      build_sharded_step, sharded_init)
     from repro.train.step import make_model_compressor
     from repro.train.trainer import Trainer, TrainerConfig
 
+    # each spec entry as a list of mesh axis names: compared structurally,
+    # since PartitionSpec's repr differs between jax releases
+    axes = lambda s: [[] if e is None else list(e) if isinstance(e, tuple)
+                      else [e] for e in s]
     cfg = ModelConfig(name="t", arch_type="dense", source="t", d_model=64,
                       vocab_size=128, pattern=(attn(),), repeats=2,
                       n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128,
@@ -380,7 +384,7 @@ _SHARDING_SUBPROC = textwrap.dedent("""
     data = LMDataConfig(vocab_size=128, seq_len=32, batch=8)
     bf = lambda i: lm_batch(data, i)
     out = {}
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         # the exact path launch/train.py takes
         jstep, st_sh, b_sh, st_abs = build_sharded_step(
             cfg, mesh, comp, opt, sample_batch=bf(0), remat_scan=False)
@@ -388,7 +392,7 @@ _SHARDING_SUBPROC = textwrap.dedent("""
                              st_sh)
         # state born on the mesh with the derived shardings
         out["init_err_specs"] = sorted(
-            str(v.sharding.spec) for v in state["comp"]["err"].values())
+            axes(v.sharding.spec) for v in state["comp"]["err"].values())
         ck_async = tempfile.mktemp()
         runner = AsyncRunner(jstep, bf,
                              RuntimeConfig(steps=3, log_every=100,
@@ -400,7 +404,7 @@ _SHARDING_SUBPROC = textwrap.dedent("""
         # by default, replicating error feedback over `model`)
         out["step"] = int(jax.device_get(state["step"]))
         out["err_specs"] = sorted(
-            str(v.sharding.spec) for v in state["comp"]["err"].values())
+            axes(v.sharding.spec) for v in state["comp"]["err"].values())
         # background-saved checkpoint must bit-for-bit match the sync
         # trainer's (regression: the packed snapshot's mixed-sharding
         # concat partial-SUMMED over the model axis — counters doubled)
@@ -435,8 +439,7 @@ def test_launcher_step_carries_derived_shardings():
     assert res["ckpt_step"] == 3 and res["ckpt_match"]
     for specs in (res["init_err_specs"], res["err_specs"]):
         # every error-feedback leaf leads with the per-worker DP dim...
-        assert specs and all(s.startswith("PartitionSpec(('data',)")
-                             for s in specs), specs
+        assert specs and all(s[0] == ["data"] for s in specs), specs
         # ...and at least one (embed/head-sized) leaf shards its inner
         # dims over the model axis instead of replicating
-        assert any("'model'" in s for s in specs), specs
+        assert any("model" in e for s in specs for e in s), specs

@@ -147,7 +147,7 @@ def test_quantize_kv_roundtrip(bits, shape):
 @pytest.mark.parametrize("bits", [4, 8])
 @pytest.mark.parametrize("shape", [(2, 2, 16, 8), (2, 1, 11, 7)])
 def test_quantize_kv_backends_byte_identical(bits, shape):
-    """Pallas (interpret off-TPU) and jnp_ref must produce the same BYTES,
+    """Pallas (interpret on the CPU) and jnp_ref must produce the same BYTES,
     so accounting and parity transfer to the TPU path unchanged."""
     from repro.serving.kv_cache import dequantize_kv, quantize_kv
 

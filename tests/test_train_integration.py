@@ -110,7 +110,7 @@ _SUBPROC = textwrap.dedent("""
     from repro.configs.base import ModelConfig, attn
     from repro.core import CompressorConfig
     from repro.data.synthetic import LMDataConfig, lm_batch
-    from repro.launch.mesh import make_mesh, use_mesh
+    from repro.launch.mesh import make_mesh
     from repro.train.optimizer import sgd
     from repro.train.step import (build_train_step, init_train_state,
                                   make_model_compressor, n_dp_of)
@@ -127,7 +127,7 @@ _SUBPROC = textwrap.dedent("""
         step_fn, st_sh, b_sh = build_train_step(cfg, mesh, comp, opt,
                                                 remat_scan=False)
         data = LMDataConfig(vocab_size=128, seq_len=32, batch=8)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = init_train_state(cfg, jax.random.PRNGKey(0), opt, comp,
                                      n_dp_of(mesh))
             jstep = jax.jit(step_fn, donate_argnums=0)

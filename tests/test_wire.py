@@ -286,12 +286,16 @@ _SERVER_SHARDING_SUBPROC = textwrap.dedent("""
     from repro.configs.base import ModelConfig, attn
     from repro.core import CompressorConfig
     from repro.data.synthetic import LMDataConfig, lm_batch
-    from repro.launch.mesh import make_mesh, use_mesh
+    from repro.launch.mesh import make_mesh
     from repro.train.optimizer import sgd
     from repro.train.runtime import (AsyncRunner, RuntimeConfig,
                                      build_sharded_step, sharded_init)
     from repro.train.step import make_model_compressor
 
+    # each spec entry as a list of mesh axis names: compared structurally,
+    # since PartitionSpec's repr differs between jax releases
+    axes = lambda s: [[] if e is None else list(e) if isinstance(e, tuple)
+                      else [e] for e in s]
     cfg = ModelConfig(name="t", arch_type="dense", source="t", d_model=64,
                       vocab_size=128, pattern=(attn(),), repeats=2,
                       n_heads=4, n_kv_heads=2, head_dim=16, d_ff=128,
@@ -306,7 +310,7 @@ _SERVER_SHARDING_SUBPROC = textwrap.dedent("""
     data = LMDataConfig(vocab_size=128, seq_len=32, batch=8)
     bf = lambda i: lm_batch(data, i)
     out = {}
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jstep, st_sh, b_sh, st_abs = build_sharded_step(
             cfg, mesh, comp, opt, sample_batch=bf(0), remat_scan=False)
         state = sharded_init(cfg, jax.random.PRNGKey(0), opt, comp, mesh,
@@ -317,9 +321,9 @@ _SERVER_SHARDING_SUBPROC = textwrap.dedent("""
         out["step"] = int(jax.device_get(state["step"]))
         out["has_out_ns"] = "lazy_out" in state["comp"]
         out["lazy_ref"] = sorted(
-            str(v.sharding.spec) for v in state["comp"]["lazy_ref"].values())
+            axes(v.sharding.spec) for v in state["comp"]["lazy_ref"].values())
         out["stale"] = sorted(
-            str(v.sharding.spec) for v in state["comp"]["lazy_stale"].values())
+            axes(v.sharding.spec) for v in state["comp"]["lazy_stale"].values())
     print("RESULT" + json.dumps(out))
 """)
 
@@ -341,9 +345,7 @@ def test_server_state_stays_sharded_after_launcher_steps():
     specs = res["lazy_ref"]
     # reference grads lead with the per-worker DP dim and at least one
     # (embed/head-sized) leaf shards its inner dims over the model axis
-    assert specs and all(s.startswith("PartitionSpec(('data',)")
-                         for s in specs), specs
-    assert any("'model'" in s for s in specs), specs
+    assert specs and all(s[0] == ["data"] for s in specs), specs
+    assert any("model" in e for s in specs for e in s), specs
     # per-worker staleness counters: DP dim only, replicated over model
-    assert all("model" not in s.replace("('data',)", "")
-               for s in res["stale"]), res["stale"]
+    assert all("model" not in e for s in res["stale"] for e in s), res["stale"]

@@ -77,6 +77,9 @@ __all__ = [
 ]
 
 CODEC_BACKENDS = ("jnp_ref", "pallas")
+# jax.named_scope tags of codec_phase's two halves (HLO metadata only)
+ENCODE_SCOPE = "codec.encode"
+DECODE_SCOPE = "codec.decode"
 
 
 # --------------------------------------------------------------------------
@@ -645,18 +648,21 @@ def codec_phase(xs: Sequence[jax.Array], stacked_flags: Sequence[bool],
     wt = as_wire(comm)
 
     # ---- shared quantization grid: per-instance global max ---------------
+    # the codec's phases carry ENCODE (scale, quantise, pack) and DECODE
+    # (unpack, dequantise, average) tags: HLO metadata for the trace's split
     if codec.needs_scale:
-        local = [_local_absmax(x, st) for x, st in zip(xs, stacked_flags)]
-        if fuse:
-            gmax = comm.fused_pmax(local)
-        else:
-            gmax = [comm.pmax(l) for l in local]
+        with jax.named_scope(ENCODE_SCOPE):
+            local = [_local_absmax(x, st) for x, st in zip(xs, stacked_flags)]
+            if fuse:
+                gmax = comm.fused_pmax(local)
+            else:
+                gmax = [comm.pmax(l) for l in local]
+            safes = [jnp.where(s > 0, s, 1.0) for s in gmax]
+            xn = [x / s for x, s in zip(xs, safes)]
         # the scale sideband is a real collective on the interconnect — one
         # fused pmax, or one per tensor — and is charged where it fires (its
         # BITS ride in codec.scale_bits with the payload accounting below)
         rec.add(0, 1 if fuse else n)
-        safes = [jnp.where(s > 0, s, 1.0) for s in gmax]
-        xn = [x / s for x, s in zip(xs, safes)]
         n_scales = [s.size for s in safes]
     else:
         safes = [None] * n
@@ -670,24 +676,27 @@ def codec_phase(xs: Sequence[jax.Array], stacked_flags: Sequence[bool],
     if wire == "psum_sim":
         outs = []
         for i, (x, safe, key, ns) in enumerate(zip(xn, safes, keys, n_scales)):
-            c = codec.codes(x, key=key)
+            with jax.named_scope(ENCODE_SCOPE):
+                c = codec.codes(x, key=key)
             # charge the PACKED container (codec.wire_bits), not x.size *
             # codec.bits: odd-length b<=4 tensors round up to a whole byte
             # on the real wire, so accounting agrees with 'allgather_codes'
             payload = (account_bits[i] if account_bits is not None
                        else codec.wire_bits(x.size))
             rec.add(payload + codec.scale_bits(ns), 1)
-            if avg_mode == "paper":
-                val = codec.expand(wt.pmean(c.astype(jnp.float32)))
-            else:
-                val = wt.pmean(codec.expand(c.astype(jnp.float32)))
-            outs.append(_rescale(val, safe))
+            with jax.named_scope(DECODE_SCOPE):
+                if avg_mode == "paper":
+                    val = codec.expand(wt.pmean(c.astype(jnp.float32)))
+                else:
+                    val = wt.pmean(codec.expand(c.astype(jnp.float32)))
+                outs.append(_rescale(val, safe))
         return outs
     if wire != "allgather_codes":
         raise ValueError(f"unknown wire mode {wire!r}")
 
     # ---- exact wire: encode -> (fused) all-gather -> decode --------------
-    wires = [codec.encode(x, key=key) for x, key in zip(xn, keys)]
+    with jax.named_scope(ENCODE_SCOPE):
+        wires = [codec.encode(x, key=key) for x, key in zip(xn, keys)]
     for i, (w, ns) in enumerate(zip(wires, n_scales)):
         payload = (account_bits[i] if account_bits is not None
                    else w.size * w.dtype.itemsize * 8)
@@ -701,10 +710,11 @@ def codec_phase(xs: Sequence[jax.Array], stacked_flags: Sequence[bool],
 
     outs = []
     for g, x, safe in zip(gathered, xs, safes):
-        codes = codec.decode(g, x.size).reshape((g.shape[0],) + x.shape)
-        if avg_mode == "paper":
-            val = codec.expand(wt.average(codes))
-        else:
-            val = wt.average(codec.expand(codes))
-        outs.append(_rescale(val, safe))
+        with jax.named_scope(DECODE_SCOPE):
+            codes = codec.decode(g, x.size).reshape((g.shape[0],) + x.shape)
+            if avg_mode == "paper":
+                val = codec.expand(wt.average(codes))
+            else:
+                val = wt.average(codec.expand(codes))
+            outs.append(_rescale(val, safe))
     return outs

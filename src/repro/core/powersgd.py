@@ -53,6 +53,12 @@ __all__ = ["PowerSGDCompressor", "PowerSGDHandler"]
 
 PyTree = Any
 
+# jax.named_scope tags of Algorithm 1's low-rank phases (HLO metadata only):
+# the power iteration over G (error-feedback add, P = GQ, Q = G^T P^,
+# reconstruct, error-feedback write) and the orthonormalisation of P
+POWER_SCOPE = "lowrank.power"
+ORTH_SCOPE = "lowrank.orth"
+
 
 def _mat_ops(pl: LeafPlan):
     """(to_2d, P-matmul, Q-matmul, orth, reconstruct) for a leaf plan."""
@@ -187,10 +193,12 @@ class PowerSGDHandler(LeafGroupHandler):
             # ---- P phase ----
             g_efs, ps = [], []
             for (i, g, pl), (shp, mm_p, _, _, _) in zip(comp, ops):
-                g_ef = (g.astype(jnp.float32).reshape(shp)
-                        + state["err"][str(i)].astype(jnp.float32).reshape(shp))
-                g_efs.append(g_ef)                                # Alg.1 l.4
-                ps.append(mm_p(g_ef, state["q"][str(i)]))         # Alg.1 l.10
+                with jax.named_scope(POWER_SCOPE):
+                    g_ef = (g.astype(jnp.float32).reshape(shp)
+                            + state["err"][str(i)].astype(jnp.float32)
+                            .reshape(shp))                        # Alg.1 l.4
+                    ps.append(mm_p(g_ef, state["q"][str(i)]))     # Alg.1 l.10
+                g_efs.append(g_ef)
             ps = self._phase(ps, flags,
                              [self._codec_p(pl) for _, _, pl in comp],
                              comm, rec,
@@ -199,9 +207,11 @@ class PowerSGDHandler(LeafGroupHandler):
             # ---- orthonormalize + Q phase ----
             p_hats, qs = [], []
             for (_, mm_p, mm_q, orth, _), g_ef, p in zip(ops, g_efs, ps):
-                p_hat = orth(p)                                   # Alg.1 l.11
+                with jax.named_scope(ORTH_SCOPE):
+                    p_hat = orth(p)                               # Alg.1 l.11
                 p_hats.append(p_hat)
-                qs.append(mm_q(g_ef, p_hat))                      # Alg.1 l.15
+                with jax.named_scope(POWER_SCOPE):
+                    qs.append(mm_q(g_ef, p_hat))                  # Alg.1 l.15
             qs = self._phase(qs, flags,
                              [self._codec_q(pl) for _, _, pl in comp],
                              comm, rec,
@@ -210,11 +220,12 @@ class PowerSGDHandler(LeafGroupHandler):
             # ---- reconstruct + error feedback ----
             for (i, g, pl), (_, _, _, _, recon), g_ef, p_hat, q_new in zip(
                     comp, ops, g_efs, p_hats, qs):
-                g_hat = recon(p_hat, q_new)                       # Alg.1 l.19
-                new_err[str(i)] = (g_ef - g_hat).reshape(pl.shape).astype(
-                    jnp.dtype(self.cfg.state_dtype))              # Alg.1 l.20
+                with jax.named_scope(POWER_SCOPE):
+                    g_hat = recon(p_hat, q_new)                   # Alg.1 l.19
+                    new_err[str(i)] = (g_ef - g_hat).reshape(pl.shape).astype(
+                        jnp.dtype(self.cfg.state_dtype))          # Alg.1 l.20
+                    outs[i] = g_hat.reshape(pl.shape).astype(g.dtype)
                 new_q[str(i)] = q_new
-                outs[i] = g_hat.reshape(pl.shape).astype(g.dtype)
         return outs, {"err": new_err, "q": new_q}
 
     # ----------------------------------------------------------- accounting

@@ -55,21 +55,25 @@ def layer_forward(p: Params, x: jax.Array, spec: LayerSpec, cfg: ModelConfig, *,
                   ) -> tuple[jax.Array, Params | None, jax.Array]:
     """Pre-norm residual block. Returns (x, new_cache, moe_aux)."""
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    if spec.kind == "attn":
-        fwd = mla_forward if cfg.use_mla else attn_forward
-        mix, new_cache = fwd(p["mixer"], h, spec, cfg, positions=positions,
-                             cache=cache, cache_index=cache_index,
-                             backend=backend)
-    else:
-        mix, new_cache = mamba_forward(p["mixer"], h, cfg, cache=cache,
-                                       cache_index=cache_index)
+    # sub-layer tags: the chip benchmark's trace reduction splits the
+    # model's device time by them (they change HLO metadata only)
+    with jax.named_scope("model.mixer"):
+        if spec.kind == "attn":
+            fwd = mla_forward if cfg.use_mla else attn_forward
+            mix, new_cache = fwd(p["mixer"], h, spec, cfg, positions=positions,
+                                 cache=cache, cache_index=cache_index,
+                                 backend=backend)
+        else:
+            mix, new_cache = mamba_forward(p["mixer"], h, cfg, cache=cache,
+                                           cache_index=cache_index)
     x = x + mix
     aux = jnp.zeros((), jnp.float32)
     if has_ffn(spec, cfg):
         h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-        if spec.moe:
-            y, aux = moe_forward(p["ffn"], h2, cfg, cfg.mlp_act)
-        else:
-            y = mlp_forward(p["ffn"], h2, cfg.mlp_act)
+        with jax.named_scope("model.mlp"):
+            if spec.moe:
+                y, aux = moe_forward(p["ffn"], h2, cfg, cfg.mlp_act)
+            else:
+                y = mlp_forward(p["ffn"], h2, cfg.mlp_act)
         x = x + y
     return x, new_cache, aux

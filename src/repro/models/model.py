@@ -95,23 +95,30 @@ def init_caches(cfg: ModelConfig, batch: int, max_seq: int, dtype) -> Params:
 
 
 # ---------------------------------------------------------------- embed/head
+# the vocabulary end (embedding, final norm, LM head; the loss adds its
+# cross-entropy) carries one tag, HEAD_SCOPE, for the trace's split
+HEAD_SCOPE = "model.head"
+
+
 def _embed(params: Params, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
-    if cfg.n_codebooks:
-        # tokens (B, S, n_cb): sum codebook embeddings (MusicGen delay pattern)
-        embs = [params["embed"][cb][tokens[..., cb]]
-                for cb in range(cfg.n_codebooks)]
-        return sum(embs)
-    return params["embed"][tokens]
+    with jax.named_scope(HEAD_SCOPE):
+        if cfg.n_codebooks:
+            # tokens (B, S, n_cb): sum codebook embeddings (MusicGen delay)
+            embs = [params["embed"][cb][tokens[..., cb]]
+                    for cb in range(cfg.n_codebooks)]
+            return sum(embs)
+        return params["embed"][tokens]
 
 
 def _head(params: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    if cfg.tie_embeddings:
+    with jax.named_scope(HEAD_SCOPE):
+        if cfg.tie_embeddings:
+            if cfg.n_codebooks:
+                return jnp.einsum("bsd,cvd->bscv", x, params["embed"])
+            return x @ params["embed"].T
         if cfg.n_codebooks:
-            return jnp.einsum("bsd,cvd->bscv", x, params["embed"])
-        return x @ params["embed"].T
-    if cfg.n_codebooks:
-        return jnp.einsum("bsd,cdv->bscv", x, params["head"])
-    return x @ params["head"]
+            return jnp.einsum("bsd,cdv->bscv", x, params["head"])
+        return x @ params["head"]
 
 
 # ---------------------------------------------------------------- forward
@@ -212,7 +219,8 @@ def forward(params: Params, tokens: jax.Array, cfg: ModelConfig, *,
         if new_caches is not None:
             new_caches["tail"].append(nc)
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope(HEAD_SCOPE):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if offset:
         x = x[:, offset:]
     if return_hidden:

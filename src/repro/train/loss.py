@@ -7,15 +7,15 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.model import forward
+from repro.models.model import HEAD_SCOPE, forward
 
 __all__ = ["lm_loss"]
 
 
 def _ce(logits: jax.Array, targets: jax.Array) -> jax.Array:
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return nll
+    with jax.named_scope(HEAD_SCOPE):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        return -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
 
 
 def lm_loss(params: Any, batch: dict[str, jax.Array], cfg: ModelConfig, *,

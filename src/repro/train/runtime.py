@@ -40,9 +40,16 @@ completed, so warm-Q truncations are never re-applied to state past them.
 bit-for-bit equal to ``Trainer`` on the same jitted step (tested), and
 ``benchmarks/step_time.py`` tracks the wall-clock delta as a first-class
 regression quantity (``BENCH_step_time.json``).
+
+Host spans: each phase of the loop (``SPANS``) is a
+``jax.profiler.TraceAnnotation`` — on the profiler's clock, beside the
+device's ops, when a trace is being taken — and ``time.perf_counter``
+seconds summed into ``AsyncRunner.span_s[name]``. ``host_s`` is the sum of
+the ``BLOCKING_SPANS``, the time the main thread waits.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import queue
@@ -60,9 +67,19 @@ from repro.train.step import build_train_step, init_train_state, n_dp_of
 from repro.train.trainer import TrainerConfig
 
 __all__ = ["RuntimeConfig", "AsyncRunner", "build_sharded_step",
-           "sharded_init", "run_schedule"]
+           "sharded_init", "run_schedule", "SPANS", "BLOCKING_SPANS"]
 
 PyTree = Any
+
+PREFETCH_WAIT = "runtime.prefetch_wait"  # main thread waits for a batch
+DISPATCH = "runtime.dispatch"            # the step_fn call (enqueues the step)
+METRICS_FETCH = "runtime.metrics_fetch"  # an earlier interval's metrics
+DRAIN = "runtime.drain"                  # run's end: last metrics, ckpt writes
+CKPT_SUBMIT = "runtime.ckpt_submit"      # snapshot dispatch for a checkpoint
+BATCH_BUILD = "runtime.batch_build"      # prefetch thread: batch_fn(i)
+SPANS = (PREFETCH_WAIT, DISPATCH, METRICS_FETCH, DRAIN, CKPT_SUBMIT,
+         BATCH_BUILD)
+BLOCKING_SPANS = (PREFETCH_WAIT, METRICS_FETCH, DRAIN, CKPT_SUBMIT)
 
 
 @dataclasses.dataclass
@@ -257,11 +274,34 @@ class AsyncRunner:
         self.batch_fn = batch_fn
         self.cfg = cfg
         self.history: list[dict[str, float]] = []
-        self.host_s = 0.0   # main-thread blocked time (cf. Trainer.host_s)
+        # seconds per host span, summed over runs (the prefetch thread
+        # adds batch_build, so updates take the lock)
+        self.span_s: dict[str, float] = dict.fromkeys(SPANS, 0.0)
+        self._span_lock = threading.Lock()
         self._t0: float | None = None
 
+    @property
+    def host_s(self) -> float:
+        """Main-thread blocked time (cf. ``Trainer.host_s``): the blocking
+        spans' sum; dispatch and the prefetch thread are not in it."""
+        return sum(self.span_s[name] for name in BLOCKING_SPANS)
+
+    @contextlib.contextmanager
+    def _span(self, name: str):
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(name):
+                yield
+        finally:
+            dt = time.perf_counter() - t0
+            with self._span_lock:
+                self.span_s[name] += dt
+
+    def _build_batch(self, i: int) -> Any:
+        with self._span(BATCH_BUILD):
+            return self.batch_fn(i)
+
     def _emit(self, step: int, metrics: Any) -> None:
-        th = time.time()
         # ONE transfer for the whole metric dict — per-metric float() pays
         # a separate host sync per value (the sync loop's behavior)
         m = {k: float(v) for k, v in jax.device_get(metrics).items()}
@@ -274,7 +314,6 @@ class AsyncRunner:
             msg = " ".join(f"{k}={v:.4f}" for k, v in m.items()
                            if k not in ("step", "wall_s"))
             print(f"step {step:5d} | {msg} | t={m['wall_s']}s")
-        self.host_s += time.time() - th
 
     def run(self, state: Any, start_step: int | None = None) -> Any:
         if start_step is None:
@@ -285,7 +324,7 @@ class AsyncRunner:
             self._t0 = time.time()
         cfg = self.cfg
         saver = AsyncCheckpointer(cfg.ckpt_path) if cfg.ckpt_every else None
-        pf = _Prefetcher(self.batch_fn, start_step, cfg.steps,
+        pf = _Prefetcher(self._build_batch, start_step, cfg.steps,
                          depth=cfg.prefetch)
         pending: list[tuple[int, Any]] = []
         # the jitted step makes many brief GIL round-trips while it blocks;
@@ -296,10 +335,10 @@ class AsyncRunner:
         sys.setswitchinterval(1e-4)
         try:
             for step in range(start_step, cfg.steps):
-                th = time.time()
-                batch = pf.get()
-                self.host_s += time.time() - th
-                state, metrics = self.step_fn(state, batch)
+                with self._span(PREFETCH_WAIT):
+                    batch = pf.get()
+                with self._span(DISPATCH):
+                    state, metrics = self.step_fn(state, batch)
                 if (step % cfg.log_every == 0
                         or step == cfg.steps - 1):
                     pending.append((step, metrics))
@@ -307,19 +346,20 @@ class AsyncRunner:
                 # already queued on the device, so the float() sync below
                 # overlaps compute instead of stalling dispatch
                 while len(pending) > 1:
-                    self._emit(*pending.pop(0))
+                    with self._span(METRICS_FETCH):
+                        self._emit(*pending.pop(0))
                 if saver and (step == cfg.steps - 1
                               or (step and step % cfg.ckpt_every == 0)):
-                    th = time.time()
                     # device-side packed copy: dispatched before the next
                     # step donates `state`, so the writer thread reads a
                     # stable snapshot while training runs ahead
-                    saver.submit(_packer_for(state).snapshot(state))
-                    self.host_s += time.time() - th
-            while pending:
-                self._emit(*pending.pop(0))
-            if saver:
-                saver.drain()   # surface background write errors
+                    with self._span(CKPT_SUBMIT):
+                        saver.submit(_packer_for(state).snapshot(state))
+            with self._span(DRAIN):
+                while pending:
+                    self._emit(*pending.pop(0))
+                if saver:
+                    saver.drain()   # surface background write errors
         finally:
             sys.setswitchinterval(prev_switch)
             pf.close()

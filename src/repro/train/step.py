@@ -141,7 +141,8 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, compressor: GradCompressor,
             metrics = jax.tree.map(lambda v: jnp.mean(v, axis=0), ms)
         comm = AxisComm(dp)
         grads, comp_local, rec = compressor.sync(grads, comp_local, comm)
-        new_params, new_opt = optimizer.update(grads, state["opt"], params)
+        with jax.named_scope("train.optimizer"):
+            new_params, new_opt = optimizer.update(grads, state["opt"], params)
         # tagged: the graph-lint shadow-collective rule allowlists these
         # scalar pmeans (they are telemetry, not wire the policy accounts)
         with jax.named_scope("train.metrics"):

@@ -6,7 +6,8 @@ insight: the quadratic-within-chunk / recurrent-across-chunk split maps
 tiles onto the MXU and the cross-chunk recurrence onto a lax.scan carry);
 inter-chunk states propagate through a sequential ``lax.scan`` (memory-light
 and sharding-friendly: batch/head dims stay partitioned, the scan is over
-time only).
+time only). B and C stay per group, (B,S,G,N), as the projection makes
+them: C·Bᵀ is contracted once per group, never per head.
 
 ``ssd_naive`` is the step-by-step recurrence oracle used by tests; the
 chunked path must match it for every chunk size.
@@ -32,7 +33,8 @@ Params = dict[str, Any]
 
 # --------------------------------------------------------------------------
 # Core SSD math. Shapes: x (B,S,H,P) already dt-weighted; a (B,S,H) = dt*A
-# (log-decay per step, <= 0); Bm/Cm (B,S,H,N) (groups pre-broadcast).
+# (log-decay per step, <= 0); Bm/Cm (B,S,G,N), H % G == 0 (``ssd_naive``
+# takes them per head, G == H).
 # --------------------------------------------------------------------------
 def ssd_naive(x, a, bm, cm, h0=None):
     """Sequential recurrence oracle: h_t = e^{a_t} h_{t-1} + B_t x_t^T."""
@@ -65,35 +67,53 @@ def _segsum(a):
 
 
 def ssd_chunked(x, a, bm, cm, chunk: int, h0=None):
-    """Chunked SSD; matches ``ssd_naive`` exactly (up to fp assoc error).
+    """Chunked SSD; matches ``ssd_naive`` on B and C repeated to heads
+    (up to fp assoc error).
+
+    ``bm``/``cm`` are per group, (B,S,G,N) with ``H % G == 0``: heads
+    ``g*H/G .. (g+1)*H/G - 1`` read group g. C·Bᵀ is contracted once per
+    group and broadcast over the group's heads at the decay mask; G == H is
+    the per-head computation.
 
     Returns (y (B,S,H,P), final_state (B,H,P,N)).
     """
     b, s, h, p = x.shape
-    n = bm.shape[-1]
+    g, n = bm.shape[-2:]
+    if h % g:
+        raise ValueError(f"{h} heads do not split into {g} B/C groups")
+    r = h // g
     pad = (-s) % chunk
     if pad:
         zpad = lambda t: jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2))
         x, a, bm, cm = map(zpad, (x, a, bm, cm))
     sp = x.shape[1]
     nc = sp // chunk
-    # chunked views: (B, nc, Q, ...)
+    # chunked views: (B, nc, Q, ...). x and y keep their heads whole; only
+    # the grouped contractions view them as (G, R): split for the whole SSD,
+    # the TPU relayouts y twice per pass instead of once.
     xc = x.reshape(b, nc, chunk, h, p).astype(jnp.float32)
-    ac = a.reshape(b, nc, chunk, h).transpose(0, 3, 1, 2)  # (B,H,nc,Q)
-    bc = bm.reshape(b, nc, chunk, h, n).astype(jnp.float32)
-    cc = cm.reshape(b, nc, chunk, h, n).astype(jnp.float32)
+    ac = a.reshape(b, nc, chunk, h).transpose(0, 1, 3, 2)  # (B,nc,H,Q)
+    bc = bm.reshape(b, nc, chunk, g, n).astype(jnp.float32)
+    cc = cm.reshape(b, nc, chunk, g, n).astype(jnp.float32)
 
-    a_cum = jnp.cumsum(ac, axis=-1)                        # (B,H,nc,Q)
+    a_cum = jnp.cumsum(ac, axis=-1)                        # (B,nc,H,Q)
     # ---- intra-chunk (quadratic, attention-like) -------------------------
-    L = jnp.exp(_segsum(ac))                               # (B,H,nc,Q,Q)
-    y_diag = jnp.einsum("bclhn,bcshn,bhcls,bcshp->bclhp", cc, bc, L, xc)
+    with jax.named_scope("ssd.cb"):
+        cb = jnp.einsum("bclgn,bcsgn->bcgls", cc, bc)      # (B,nc,G,Q,Q)
+    with jax.named_scope("ssd.diag"):
+        cb = jnp.broadcast_to(cb[:, :, :, None], (b, nc, g, r, chunk, chunk))
+        mask = cb.reshape(b, nc, h, chunk, chunk) * jnp.exp(_segsum(ac))
+        y_diag = jnp.einsum("bchls,bcshp->bclhp", mask, xc)
     # ---- per-chunk summary states ----------------------------------------
-    decay_states = jnp.exp(a_cum[..., -1:] - a_cum)        # (B,H,nc,Q)
-    states = jnp.einsum("bclhn,bhcl,bclhp->bchpn", bc, decay_states, xc)
+    with jax.named_scope("ssd.states"):
+        decay_states = jnp.exp(a_cum[..., -1:] - a_cum)    # (B,nc,H,Q)
+        xw = xc * decay_states.transpose(0, 1, 3, 2)[..., None]
+        states = jnp.einsum("bclgrp,bclgn->bcgrpn",
+                            xw.reshape(b, nc, chunk, g, r, p), bc)
     # ---- inter-chunk recurrence (sequential scan over chunks) ------------
     if h0 is None:
         h0 = jnp.zeros((b, h, p, n), jnp.float32)
-    chunk_decay = jnp.exp(a_cum[..., -1])                  # (B,H,nc)
+    chunk_decay = jnp.exp(a_cum[..., -1])                  # (B,nc,H)
 
     def step(carry, inp):
         st, dec = inp                                      # (B,H,P,N),(B,H)
@@ -102,12 +122,15 @@ def ssd_chunked(x, a, bm, cm, chunk: int, h0=None):
         return new, prev                                   # emit state BEFORE chunk
 
     hT, prev_states = jax.lax.scan(
-        step, h0, (states.transpose(1, 0, 2, 3, 4),
-                   chunk_decay.transpose(2, 0, 1)))
-    prev_states = prev_states.transpose(1, 0, 2, 3, 4)     # (B,nc,H,P,N)
+        step, h0, (states.reshape(b, nc, h, p, n).transpose(1, 0, 2, 3, 4),
+                   chunk_decay.transpose(1, 0, 2)))
     # ---- contribution of carried-in state to each position ---------------
-    state_decay = jnp.exp(a_cum)                           # (B,H,nc,Q)
-    y_off = jnp.einsum("bclhn,bchpn,bhcl->bclhp", cc, prev_states, state_decay)
+    with jax.named_scope("ssd.off"):
+        prev_states = prev_states.transpose(1, 0, 2, 3, 4).reshape(
+            b, nc, g, r, p, n)                             # (B,nc,G,R,P,N)
+        y_off = jnp.einsum("bclgn,bcgrpn->bclgrp", cc, prev_states)
+        state_decay = jnp.exp(a_cum).transpose(0, 1, 3, 2)  # (B,nc,Q,H)
+        y_off = y_off.reshape(b, nc, chunk, h, p) * state_decay[..., None]
     y = (y_diag + y_off).reshape(b, sp, h, p)
     return y[:, :s], hT
 
@@ -171,9 +194,6 @@ def _ssm_inputs(xbc_conv, dt_raw, p: Params, cfg: ModelConfig):
     xs = xbc_conv[..., :di].reshape(b, s, h, cfg.ssm_head_dim)
     bm = xbc_conv[..., di:di + g * n].reshape(b, s, g, n)
     cm = xbc_conv[..., di + g * n:].reshape(b, s, g, n)
-    rep = h // g
-    bm = jnp.repeat(bm, rep, axis=2)
-    cm = jnp.repeat(cm, rep, axis=2)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])  # (B,S,H)
     a = -jnp.exp(p["A_log"].astype(jnp.float32))                     # (H,)
     return xs, bm, cm, dt, a
@@ -219,10 +239,13 @@ def _mamba_step(p: Params, cfg: ModelConfig, z, xbc, dt_raw, cache):
     conv = jnp.einsum("bkc,kc->bc", window, p["conv_w"]) + p["conv_b"]
     xs, bm, cm, dt, a = _ssm_inputs(conv[:, None, :], dt_raw, p, cfg)
     xs, bm, cm, dt = xs[:, 0], bm[:, 0], cm[:, 0], dt[:, 0]  # drop seq dim
+    r, hp = h // g, cfg.ssm_head_dim
     decay = jnp.exp(dt * a[None, :])                          # (B,H)
+    xg = (xs * dt[..., None]).reshape(b, g, r, hp)            # heads as (G,R)
     hs = cache["ssm"] * decay[..., None, None] + jnp.einsum(
-        "bhp,bhn->bhpn", xs * dt[..., None], bm)
-    y = jnp.einsum("bhpn,bhn->bhp", hs, cm) + xs * p["D"][None, :, None]
+        "bgrp,bgn->bgrpn", xg, bm).reshape(b, h, hp, n)
+    y = jnp.einsum("bgrpn,bgn->bgrp", hs.reshape(b, g, r, hp, n), cm)
+    y = y.reshape(b, h, hp) + xs * p["D"][None, :, None]
     y = y.reshape(b, 1, di).astype(z.dtype)
     y = gated_rms_norm(y, z, p["norm"], cfg.norm_eps)
     out = y @ p["out_proj"].astype(z.dtype)

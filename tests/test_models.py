@@ -60,6 +60,57 @@ class TestSSD:
         y1, _ = ssd_chunked(x, a, bm, cm, chunk)
         np.testing.assert_allclose(np.asarray(y1), np.asarray(y0), atol=5e-5)
 
+    @pytest.mark.parametrize("chunk", [6, 5])
+    @pytest.mark.parametrize("with_h0", [False, True])
+    @pytest.mark.parametrize("groups", [1, 2, 4])
+    def test_grouped_bc_matches_per_head(self, groups, with_h0, chunk):
+        """B and C per group, (B,S,G,N): y, the final state and the
+        gradients of x, a, B and C equal the same call with B and C
+        repeated to heads, and the oracle on the repeated inputs."""
+        b, s, h, p, n = 2, 18, 4, 8, 16
+        ks = jax.random.split(jax.random.PRNGKey(groups), 7)
+        x = jax.random.normal(ks[0], (b, s, h, p))
+        a = -jnp.abs(jax.random.normal(ks[1], (b, s, h))) * 0.2
+        bm = jax.random.normal(ks[2], (b, s, groups, n))
+        cm = jax.random.normal(ks[3], (b, s, groups, n))
+        h0 = jax.random.normal(ks[4], (b, h, p, n)) if with_h0 else None
+        gy = jax.random.normal(ks[5], (b, s, h, p))
+        gh = jax.random.normal(ks[6], (b, h, p, n))
+
+        def rep(t):
+            return jnp.repeat(t, h // groups, axis=2)
+
+        def outputs_and_grads(ssd):
+            def f(*args):
+                y, hT = ssd(*args)
+                return jnp.vdot(y, gy) + jnp.vdot(hT, gh), (y, hT)
+
+            grad = jax.grad(f, argnums=(0, 1, 2, 3), has_aux=True)
+            return jax.jit(grad)(x, a, bm, cm)
+
+        got = outputs_and_grads(
+            lambda x, a, bm, cm: ssd_chunked(x, a, bm, cm, chunk, h0))
+        per_head = outputs_and_grads(
+            lambda x, a, bm, cm: ssd_chunked(x, a, rep(bm), rep(cm), chunk, h0))
+        oracle = outputs_and_grads(
+            lambda x, a, bm, cm: ssd_naive(x, a, rep(bm), rep(cm), h0))
+        for want in (per_head, oracle):
+            jax.tree.map(
+                lambda u, v: np.testing.assert_allclose(
+                    np.asarray(u), np.asarray(v), rtol=1e-5, atol=1e-4),
+                got, want)
+
+    @pytest.mark.parametrize("groups", [1, 2, 8])
+    def test_grouped_decode_matches_prefill(self, groups):
+        """The decode step with grouped B and C continues a prefill as the
+        full sequence's last position does."""
+        cfg = ModelConfig(name="ssm-groups", arch_type="ssm", source="t",
+                          d_model=64, vocab_size=96, dtype="float32",
+                          pattern=(mamba(),), repeats=2, d_ff=0, ssm_state=16,
+                          ssm_head_dim=16, ssm_chunk=8, ssm_groups=groups)
+        assert cfg.ssm_heads == 8
+        _pd_check(cfg)
+
 
 # ------------------------------------------------------------------ MoE
 class TestMoE:

@@ -4,7 +4,9 @@ The TPU compiler is installed even where no chip is attached, so each
 kernel here is lowered with ``interpret=False`` and compiled for a chip of
 a described ``v5e:2x2`` topology, at the widths the training step and the
 serving cache use. A compile that passes says the kernel fits the chip's
-tiling and memory; it runs nothing and measures no time.
+tiling and memory; it runs nothing and measures no time. The chunked SSD
+(jnp einsums, no kernel) is compiled too, and held to the compiler's own
+count of its FLOPs and temporaries.
 
 The topology is described inside a module fixture, never on import: only
 one process at a time may load the TPU library, and pytest-xdist workers
@@ -20,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import log_quant
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.ssd_chunk import ssd_chunk_pallas
+from repro.models.ssm import ssd_chunked
 
 # mamba2-370m LQ-SGD factors: the tied embedding's P (vocab x rank 1) and
 # the in_proj Q stacked over 48 layers (2*2048 + 2*128 + 32 = 4384 wide)
@@ -110,3 +113,26 @@ def test_ssd_chunk_compiles(one_chip):
         ((b, h, nc, q, n), jnp.float32),
     )
     assert "tpu_custom_call" in text
+
+
+def test_ssd_grouped_core_cost(one_chip):
+    # mamba2-370m's SSD core at the one-chip cell's widths, forward, remat
+    # recompute and backward: 8 x 2048 tokens, 32 heads of 64, state 128,
+    # chunk 256, B and C in one group. C·Bᵀ per group gives 9.2e10 FLOPs and
+    # 0.81 GB of temporaries; per head, as with B and C repeated to every
+    # head, 1.9e11 and 1.3 GB.
+    b, s, h, p, g, n = 8, 2048, 32, 64, 1, 128
+    core = jax.checkpoint(functools.partial(ssd_chunked, chunk=256))
+
+    def grads(x, a, bm, cm, gy):
+        def loss(*args):
+            y, final_state = core(*args)
+            return jnp.vdot(y, gy) + final_state.sum()
+
+        return jax.grad(loss, argnums=(0, 1, 2, 3))(x, a, bm, cm)
+
+    shapes = [(b, s, h, p), (b, s, h), (b, s, g, n), (b, s, g, n), (b, s, h, p)]
+    args = [jax.ShapeDtypeStruct(x, jnp.float32, sharding=one_chip) for x in shapes]
+    compiled = jax.jit(grads).lower(*args).compile()
+    assert compiled.cost_analysis()["flops"] < 1.3e11
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9

@@ -3,10 +3,13 @@
 ``model_flops_per_token`` follows ``repro.roofline.flops_model`` with one
 change: a training token costs the forward pass three times (forward plus a
 backward of twice its cost), never the four of full rematerialisation,
-because recomputed operations do not count towards MFU. Matmuls count 2·M·N·K;
-causal attention attends S/2 keys on average; the SSD counts its chunked
-algorithm's dense intra-chunk blocks (chunk Q) and its state terms, as the
-Mamba-2 paper's chunked form computes them.
+because recomputed operations do not count towards MFU. Matmuls count 2·M·N·K.
+The forward count of each layer comes from a counter found by name: one
+module per kind under ``chipbench/counts/``, ``<kind>.py`` with
+``fwd(model, spec, seq_len) -> float``. A layer adds its mixer's count by
+``spec["kind"]`` and its FFN's, ``moe`` where ``spec["moe"]``, else ``mlp``
+where the model has ``d_ff``; the head adds 2·d_model·vocab_size. A new
+kind of layer is a new file there.
 
 ``compress_cost`` is the least work of one rank-r LQ-SGD sync (paper
 Algorithm 1), whatever implements it. Per low-rank matrix instance (n, m):
@@ -22,7 +25,10 @@ synced whole (a norm, a bias) is read once.
 
 from __future__ import annotations
 
+import functools
+import importlib.util
 import math
+from pathlib import Path
 
 __all__ = ["layer_specs", "model_flops_per_token", "compress_cost", "leaf_route"]
 
@@ -36,38 +42,30 @@ def layer_specs(model: dict) -> list[dict]:
     )
 
 
-def _attn_fwd(m: dict, seq_len: int, window: int | None) -> float:
-    d, h, hkv, hd = m["d_model"], m["n_heads"], m["n_kv_heads"], m["head_dim"]
-    ctx = seq_len / 2 if window is None else min(seq_len / 2, window)
-    proj = 2 * d * h * hd + 2 * 2 * d * hkv * hd + 2 * h * hd * d
-    return proj + 2 * 2 * ctx * h * hd
+COUNTS = Path(__file__).resolve().parent / "counts"
 
 
-def _mamba_fwd(m: dict) -> float:
-    d = m["d_model"]
-    di = m["ssm_expand"] * d
-    g, n, p, q = m["ssm_groups"], m["ssm_state"], m["ssm_head_dim"], m["ssm_chunk"]
-    h = di // p
-    proj = 2 * d * (2 * di + 2 * g * n + h) + 2 * di * d
-    conv = 2 * m["ssm_conv"] * (di + 2 * g * n)
-    ssd = 2 * h * (q * n + q * p + 2 * p * n)
-    return proj + conv + ssd
+@functools.cache
+def _counter(path: Path):
+    if not path.is_file():
+        raise ValueError(f"no FLOP counter for layer kind {path.stem!r}: {path}")
+    spec = importlib.util.spec_from_file_location(f"counts_{path.stem}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.fwd
 
 
-def model_flops_per_token(model: dict, seq_len: int) -> float:
+def model_flops_per_token(model: dict, seq_len: int, counts: Path = COUNTS) -> float:
     """Forward + backward FLOPs per trained token, no recompute."""
     fwd = 0.0
     for spec in layer_specs(model):
-        if spec["kind"] == "attn":
-            fwd += _attn_fwd(model, seq_len, spec.get("window"))
-        elif spec["kind"] == "mamba":
-            fwd += _mamba_fwd(model)
-        else:
-            raise ValueError(f"no FLOP count for layer kind {spec['kind']!r}")
+        kinds = [spec["kind"]]
         if spec.get("moe"):
-            raise ValueError("no FLOP count for expert layers yet")
-        if model.get("d_ff", 0) > 0:
-            fwd += 6 * model["d_model"] * model["d_ff"]
+            kinds.append("moe")
+        elif model.get("d_ff", 0) > 0:
+            kinds.append("mlp")
+        for kind in kinds:
+            fwd += _counter(counts / f"{kind}.py")(model, spec, seq_len)
     fwd += 2 * model["d_model"] * model["vocab_size"]
     return 3 * fwd
 

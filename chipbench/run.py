@@ -6,8 +6,14 @@ Everything about a cell is data, found by name from ``BENCHMARK.json``: its
 configuration (``chipbench/configs/<config>.json``, with the plain
 reference module it names under ``chipbench/reference/``), its traffic
 (``chipbench/traffic/<traffic>.json``), the limits of its correctness check
-(``chipbench/limits/<workload>.json``) and one reader per per-layer metric
-(``chipbench/metrics/<metric>.py``).
+(``chipbench/limits/<workload>.json``), one reader per per-layer metric
+(``chipbench/metrics/<metric>.py``) and one FLOP counter per kind of layer
+(``chipbench/counts/<kind>.py``). A reader's ``read(ctx)`` returns the
+metric's value, or None where it finds nothing to read. ``ctx`` holds the
+trace's reduction (``trace``), the parts of the model and compressor
+buckets by their tags (``parts``, ``chipbench.splits``; ms per step), the
+runtime spans' ms per step (``span_ms``, from ``AsyncRunner.span_s``), the
+chip's peaks, the window's tokens/s and the counts of ``chipbench.flops``.
 
 A run drives the program's own training path, as ``repro.launch.train``
 assembles it: the step from ``build_sharded_step`` on a ``chips`` x 1
@@ -48,6 +54,7 @@ ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "chipbench"
 WINDOW_SPAN = "chipbench.window"
 TRACE_SECONDS = 2.0  # a traced window: about this long, and two steps at least
+METADATA_IN_KEY = "jax_compilation_cache_include_metadata_in_key"
 
 
 class NoChip(Exception):
@@ -90,20 +97,53 @@ def load_cell(workload: str, bench_file: Path = ROOT / "BENCHMARK.json") -> dict
     }
 
 
+STACK = ("lead", "pattern", "tail")
+
+
+def _with_defaults(model: dict) -> dict:
+    """The file's model with the program's defaults for the fields it does
+    not give, at the top level and in each layer of its stack."""
+    from repro.configs.base import LayerSpec, ModelConfig
+
+    def defaults(cls) -> dict:
+        return {
+            f.name: f.default
+            for f in dataclasses.fields(cls)
+            if f.default is not dataclasses.MISSING
+        }
+
+    def as_dict(spec) -> dict:
+        return dataclasses.asdict(spec) if isinstance(spec, LayerSpec) else spec
+
+    layer = defaults(LayerSpec)
+    out = {**defaults(ModelConfig), **model}
+    for key in STACK:
+        out[key] = [{**layer, **as_dict(spec)} for spec in out[key]]
+    return json.loads(json.dumps(out))  # tuples -> lists, as in a file
+
+
 def model_config(config: dict):
-    """The program's ModelConfig for a configuration file; every field of
-    the file must be what the program runs."""
+    """The program's ModelConfig for a configuration file: the registry's
+    entry for ``arch`` with every field the file gives, its layer stack
+    (``lead``, ``pattern``, ``tail``) included, replaced by the file's.
+
+    What the program then runs must be what the file says: every field the
+    file gives, and the program's default for each it leaves out, so that
+    a field the program gains later leaves older files as they were."""
     from repro.configs import get_config
+    from repro.configs.base import LayerSpec
 
     model = config["model"]
-    scalars = {
-        k: v for k, v in model.items() if k not in ("pattern", "lead", "tail")
+    fields = {
+        k: tuple(LayerSpec(**spec) for spec in v) if k in STACK else v
+        for k, v in model.items()
     }
-    cfg = dataclasses.replace(get_config(config["arch"]), **scalars)
-    ran = dataclasses.asdict(cfg)
-    ran = json.loads(json.dumps(ran))  # tuples -> lists, as in the file
-    if ran != model:
-        diff = sorted(k for k in set(ran) | set(model) if ran.get(k) != model.get(k))
+    cfg = dataclasses.replace(get_config(config["arch"]), **fields)
+    cfg.validate()
+    ran = json.loads(json.dumps(dataclasses.asdict(cfg)))
+    want = _with_defaults(model)
+    if ran != want:
+        diff = sorted(k for k in set(ran) | set(want) if ran.get(k) != want.get(k))
         raise ValueError(f"config file and program disagree on {diff}")
     return cfg
 
@@ -170,6 +210,7 @@ class Cell:
         self.spec = spec
         self.traffic = tr = spec["traffic"]
         self.model = spec["config"]["model"]
+        self.init = spec["config"].get("init", {})  # leaf inits the file adds
         self.devices = devices
         self.chips = len(devices)
         self.cfg = model_config(spec["config"])
@@ -234,7 +275,7 @@ class Cell:
 
         tr = self.traffic
         with jax.set_mesh(self.mesh):
-            state = weights.make_state(seed, self.st_abs, self.st_sh)
+            state = weights.make_state(seed, self.st_abs, self.st_sh, self.init)
             rcfg = RuntimeConfig(
                 steps=0,
                 log_every=tr["log_every"],
@@ -265,7 +306,7 @@ class Cell:
         k = self.traffic["check_steps"]
         norms = {}
         with jax.default_matmul_precision("highest"):
-            p0 = weights.init_params(seed, self.st_abs["params"])
+            p0 = weights.init_params(seed, self.st_abs["params"], init=self.init)
 
             def on_step(t, tree):
                 if t in (0, k - 1):
@@ -293,7 +334,7 @@ class Cell:
 
         k = self.traffic["check_steps"]
         with jax.default_matmul_precision("highest"):
-            p0 = weights.init_params(seed, self.st_abs["params"])
+            p0 = weights.init_params(seed, self.st_abs["params"], init=self.init)
             return {
                 "losses": losses,
                 "step1": leaf_norms(snaps[1], p0),
@@ -310,14 +351,17 @@ def run_cell(
     t_start: float,
     require_tpu: bool = True,
     wrap_step=None,
+    seen: dict | None = None,
 ) -> dict:
     """One run of one cell; returns the result line as a dict.
 
-    ``wrap_step`` lets a test put a broken step in the program's place."""
+    ``wrap_step`` lets a test put a broken step in the program's place; a
+    traced run puts the compiled step's text and the trace in plain form
+    into ``seen`` (``hlo``, ``trace``) where it is given."""
     import jax
     import numpy as np
 
-    from chipbench import check, flops, peaks
+    from chipbench import check, flops, peaks, splits
 
     chips = spec["cell"]["chips"]
     devices = jax.devices()
@@ -333,6 +377,10 @@ def run_cell(
         use_compile_cache()
         # cache the small programs too, so that a warm run compiles nothing
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    if trace:
+        # the cache's key leaves source metadata out by default, so a traced
+        # run could be given a step compiled by other source, other op_names
+        jax.config.update(METADATA_IN_KEY, True)
 
     phases = {"imports": time.perf_counter() - t_start}
     with CompileCount() as setup_compiles:
@@ -362,7 +410,7 @@ def run_cell(
             n_steps = min(n_steps, max(2, round(TRACE_SECONDS / step_s)))
         first = rcfg.steps
         rcfg.steps = first + n_steps
-        host_before = runner.host_s
+        host_before, spans_before = runner.host_s, dict(runner.span_s)
         trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-") if trace else None
         with CompileCount() as compiles:
             if trace:
@@ -376,6 +424,7 @@ def run_cell(
                 jax.profiler.stop_trace()
         window_losses = [h["loss"] for h in runner.history[k_check:]]
         host_blocked_ms = 1e3 * (runner.host_s - host_before) / n_steps
+        spans = span_ms(spans_before, runner.span_s, n_steps)
         wire_mb = runner.history[-1]["wire_mb_per_step"]
         peak = _peak_bytes(devices)
         hlo = None
@@ -385,6 +434,8 @@ def run_cell(
             )
             abstract = st_abs_with(cell.st_abs, cell.st_sh)
             hlo = cell.step.lower(abstract, batch0).compile().as_text()
+            # the reference's programs are the untraced runs' own
+            jax.config.update(METADATA_IN_KEY, False)
         del state, runner
     gc.collect()
     jax.clear_caches()
@@ -408,10 +459,16 @@ def run_cell(
     if trace:
         from chipbench import trace as tr
 
-        reduced = tr.reduce_trace(
-            tr.load_xplane(trace_dir, WINDOW_SPAN), tr.scope_map(hlo), n_steps
-        )
+        plain = tr.load_xplane(trace_dir, WINDOW_SPAN)
         shutil.rmtree(trace_dir, ignore_errors=True)
+        scopes = tr.scope_map(hlo)
+        reduced = tr.reduce_trace(plain, scopes, n_steps)
+        parts = splits.split_trace(
+            splits.op_ms(plain, scopes, n_steps), splits.sub_map(hlo)
+        )
+        if seen is not None:
+            seen |= {"hlo": hlo, "trace": plain}
+        del plain
         result["device"]["busy_s"] = reduced["busy_s"]
         result["device"]["window_s"] = reduced["window_s"]
         flat = jax.tree_util.tree_flatten_with_path(cell.st_abs["params"])[0]
@@ -436,6 +493,8 @@ def run_cell(
             "compress_bytes": c_bytes,
             "compress_flops": c_flops,
             "host_blocked_ms": host_blocked_ms,
+            "span_ms": spans,
+            "parts": parts,
             "wire_mb_per_step": wire_mb,
         }
         for m in spec["per_layer"]:
@@ -446,6 +505,7 @@ def run_cell(
             "device_ops": reduced["device_ops"],
             "idle_gaps": reduced["idle_gaps"],
         }
+        result["parts"] = parts
     else:
         e2e = {
             "tokens_per_s": (tokens / window_s, "tokens/s"),
@@ -474,6 +534,12 @@ def run_cell(
         k: {"value": values[k], "limit": limits[k]} for k in sorted(limits)
     }
     return result
+
+
+def span_ms(before: dict, after: dict, steps: int) -> dict[str, float]:
+    """Milliseconds per step that each runtime span took between two
+    readings of ``AsyncRunner.span_s`` (seconds by span name)."""
+    return {k: 1e3 * (v - before.get(k, 0.0)) / steps for k, v in after.items()}
 
 
 def st_abs_with(st_abs, st_sh):

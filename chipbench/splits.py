@@ -1,42 +1,40 @@
-"""Splits of a traced step by the program's own spans and scopes.
+"""Splits of a traced step by the program's own ``jax.named_scope`` tags.
 
-``chipbench/trace.py`` reduces a trace to the benchmark's layers: the model,
-the compressor, the metrics and the collectives. The program tags finer
-work inside them, and this module reads those tags:
+``chipbench/trace.py`` reduces a trace to the benchmark's buckets: the
+model, the compressor, the metrics and the collectives. The program tags
+finer work inside them, and this module reads those tags by one open rule,
+with no table of names: every part of an HLO ``op_name`` of the form
+``<word>.<word>`` is a tag (``model.mixer``, ``ssd.cb``, ``lowrank.orth``,
+``codec.encode``), also where JAX wraps it, as in ``jvp(model.head)`` or
+``transpose(jvp(model.mlp))``; ``comp.lq_sgd.eager``, with three words, is
+a bucket's scope and no tag. An instruction's tags, outermost first, are
+its chain, and a part is named by its chain's path: ``model.mixer`` and,
+inside it, ``model.mixer/ssd.cb``.
 
-- device ``jax.named_scope`` tags, in the compiled step's HLO ``op_name``s:
-  ``model.mixer``, ``model.mlp``, ``model.head`` and ``train.optimizer``
-  inside the model bucket, and the backward pass by ``transpose(`` (its
-  remat recompute by ``rematted_computation``); ``lowrank.power``,
-  ``lowrank.orth`` and ``codec.*`` inside the compressor bucket;
-- host spans, ``jax.profiler.TraceAnnotation``s named ``runtime.*`` by
-  ``repro.train.runtime.AsyncRunner``, on every ``/host:CPU`` line (the
-  prefetch thread's included), on the clock of the device's ops.
-
-Each op keeps the bucket ``chipbench.trace.scope_map`` gives it, so a
-bucket's parts add up to the bucket. Within it, an op goes to the tag whose
-instructions make the most bytes of results, and to ``other`` without one;
-``by_count`` gives one vote per instruction instead, which reads a fusion
-of a pass over a whole gradient with the decode of a small factor as the
-decode. An idle gap of the device is named after the innermost
-``runtime.*`` span that holds its midpoint, or by ``chipbench.trace``'s
-rule where no such span holds it.
+Each op keeps the bucket ``chipbench.trace.scope_map`` gives it. Within
+it, the op's instructions (itself and those it calls) of that bucket's
+scope vote with the bytes of their results (``by_bytes``, so that a fusion
+goes to the phase that makes most of its data; ``by_count`` gives one vote
+each), and the op goes to the winning chain, or to ``other`` where none of
+them carries a tag. Parts hold the parts nested in them, so the parts
+without a ``/`` and ``other`` add up to their bucket. The backward pass is
+the ops whose instructions are mostly under JAX's ``transpose(``, and its
+remat recompute those mostly under ``rematted_computation``.
 
     python3 chipbench/splits.py --workload <cell> --seed <n> [--out <dir>]
 
 runs one traced run of the cell (``chipbench.run.run_cell``) and prints its
-result line with a ``splits`` object added. With ``--out`` it also writes
-there the compiled step (``step.hlo.txt.gz``), the step with its source
-metadata stripped (``step.stripped.hlo``, for a comparison of two commits'
-programs) and the trace in plain form with its spans (``trace.json.gz``).
-A commit whose program carries no tags reads all of its time as ``other``
-and no spans.
+result line with a ``splits`` object added: the parts by count, the ops
+whose instructions carry more than one chain, and what a runtime span
+costs. With ``--out`` it also writes there the compiled step
+(``step.hlo.txt.gz``), the step with its source metadata stripped
+(``step.stripped.hlo``, for a comparison of two commits' programs) and the
+trace in plain form with its spans (``trace.json.gz``).
 """
 
 from __future__ import annotations
 
 import collections
-import glob
 import gzip
 import json
 import os
@@ -52,28 +50,17 @@ if __name__ == "__main__":
 from chipbench import trace as tr  # noqa: E402
 from chipbench.hlo import parse_module, parse_type  # noqa: E402
 
-SPAN_PREFIX = "runtime."
-MODEL_TAGS = (
-    ("model.mixer", "mixer"),
-    ("model.mlp", "mlp"),
-    ("model.head", "head"),
-    ("train.optimizer", "optimizer"),
-)
-COMPRESS_TAGS = (
-    ("lowrank.orth", "orth"),
-    ("lowrank.power", "power"),
-    ("codec.", "codec"),
-)
+TAG = re.compile(r"(?:^|[/(])([A-Za-z_]\w*\.[A-Za-z_]\w*)(?=$|[/)])")
+BUCKETS = ("model", "compress")
 BACKWARD = "transpose("
 REMAT = "rematted_computation"
 METADATA_TABLES = ("FileNames", "FunctionNames", "FileLocations", "StackFrames")
 
 
-def _tag(op_name: str, tags) -> str | None:
-    for tag, part in tags:
-        if tag in op_name:
-            return part
-    return None
+def tags(op_name: str) -> tuple[str, ...]:
+    """The ``<word>.<word>`` tags of an ``op_name``, outermost first, each
+    once."""
+    return tuple(dict.fromkeys(TAG.findall(op_name)))
 
 
 def _result_bits(ins) -> int:
@@ -81,43 +68,55 @@ def _result_bits(ins) -> int:
 
 
 def sub_map(hlo_text: str, by_bytes: bool = True) -> dict[str, dict]:
-    """instruction name -> its part in the model and in the compressor
-    bucket, whether it is backward or remat recompute, and the parts its
-    instructions carry (``phases``).
-
-    Each of its instructions (itself and those it calls) that carries an
-    ``op_name`` votes: with the bytes of its result (``by_bytes``, so that a
-    fusion goes to the phase that makes most of its data), or one each."""
+    """instruction name -> the chain it goes to in the model and in the
+    compressor bucket (None: ``other``), whether it is backward or remat
+    recompute, and the chains its instructions carry (``phases``)."""
     mod = parse_module(hlo_text)
+    memo: dict[str, collections.Counter] = {}
 
-    def members(ins, seen: set) -> list:
-        out = [ins] if ins.op_name else []
-        for callee in ins.callees:
-            if callee in seen or callee not in mod.computations:
-                continue
-            seen.add(callee)
-            for sub in mod.computations[callee].instructions:
-                out += members(sub, seen)
-        return out
+    def own(ins) -> collections.Counter:
+        # votes as (bucket, chain) -> weight; "" counts every instruction
+        c: collections.Counter = collections.Counter()
+        if not ins.op_name:
+            return c
+        w = _result_bits(ins) if by_bytes else 1
+        c[""] += w
+        c[BACKWARD] += w if BACKWARD in ins.op_name else 0
+        c[REMAT] += w if REMAT in ins.op_name else 0
+        chain = tags(ins.op_name)
+        if chain:
+            c[(tr._scope_of(ins.op_name), chain)] += w
+        return c
+
+    def called(ins) -> collections.Counter:
+        c: collections.Counter = collections.Counter()
+        for callee in dict.fromkeys(ins.callees):
+            if callee in mod.computations:
+                c.update(computation(callee))
+        return c
+
+    def computation(name: str) -> collections.Counter:
+        if name not in memo:
+            memo[name] = collections.Counter()  # a cycle reads as empty
+            c: collections.Counter = collections.Counter()
+            for ins in mod.computations[name].instructions:
+                c.update(own(ins))
+                c.update(called(ins))
+            memo[name] = c
+        return memo[name]
 
     out = {}
     for ins in mod.instructions():
-        votes = [
-            (m.op_name, _result_bits(m) if by_bytes else 1)
-            for m in members(ins, set())
-        ]
-        total = sum(w for _, w in votes)
-        entry: dict = {"phases": []}
-        for key, tags in (("model", MODEL_TAGS), ("compress", COMPRESS_TAGS)):
-            parts: collections.Counter = collections.Counter()
-            for name, w in votes:
-                part = _tag(name, tags)
-                if part is not None:
-                    parts[part] += w
-            entry[key] = max(parts, key=parts.get) if parts else "other"
-            entry["phases"] += sorted(parts)
-        for key, tag in (("backward", BACKWARD), ("remat", REMAT)):
-            entry[key] = 2 * sum(w for name, w in votes if tag in name) > total
+        votes = own(ins) + called(ins)
+        entry: dict = {}
+        chains = [(k, w) for k, w in votes.items() if isinstance(k, tuple)]
+        for bucket in BUCKETS:
+            mine = {chain: w for (b, chain), w in chains if b == bucket}
+            entry[bucket] = max(mine, key=mine.get) if mine else None
+        entry["phases"] = sorted({"/".join(chain) for (_, chain), _ in chains})
+        total = votes[""]
+        entry["backward"] = 2 * votes[BACKWARD] > total
+        entry["remat"] = 2 * votes[REMAT] > total
         out[ins.name] = entry
     return out
 
@@ -140,93 +139,46 @@ def op_ms(trace: dict, scopes: dict, steps: int) -> dict[str, tuple[str, float]]
     return {k: (scope, ms) for k, (scope, ms) in out.items()}
 
 
-_OTHER = {"model": "other", "compress": "other", "phases": []}
+_OTHER = {"model": None, "compress": None, "phases": []}
 
 
 def split_trace(ops: dict, subs: dict) -> dict:
     """Milliseconds per step of each part of the model and compressor
-    buckets, and of the model's backward pass and its remat recompute."""
-    parts = {
-        "model": [p for _, p in MODEL_TAGS] + ["other", "bwd", "bwd_remat"],
-        "compress": [p for _, p in COMPRESS_TAGS] + ["other"],
-    }
-    out = {key: dict.fromkeys(names, 0.0) for key, names in parts.items()}
+    buckets, by path (``other`` for the untagged), and of the model's
+    backward pass (``bwd``) and its remat recompute (``bwd_remat``)."""
+    out: dict = {b: collections.Counter() for b in BUCKETS}
+    out |= {"bwd": 0.0, "bwd_remat": 0.0}
     for instr, (scope, ms) in ops.items():
         sub = subs.get(instr, _OTHER)
-        out[scope][sub[scope]] += ms
+        chain = sub[scope]
+        if chain is None:
+            out[scope]["other"] += ms
+        for i in range(len(chain or ())):
+            out[scope]["/".join(chain[: i + 1])] += ms
         if scope == "model" and sub.get("backward"):
-            out["model"]["bwd"] += ms
+            out["bwd"] += ms
             if sub.get("remat"):
-                out["model"]["bwd_remat"] += ms
+                out["bwd_remat"] += ms
+    for b in BUCKETS:
+        out[b] = dict(sorted(out[b].items()))
     return out
 
 
 def mixed_ops(ops: dict, subs: dict, by_count: dict, min_ms: float = 1.0):
     """The ops of at least ``min_ms`` per step whose instructions carry more
-    than one part's tag: [instruction, bucket, ms, part by bytes, part by
-    count, parts], longest first."""
+    than one chain: [instruction, bucket, ms, part by bytes, part by count,
+    chains], longest first."""
+
+    def path(chain) -> str:
+        return "other" if chain is None else "/".join(chain)
+
     out = []
     for instr, (scope, ms) in ops.items():
         sub = subs.get(instr, _OTHER)
         if ms >= min_ms and len(sub["phases"]) > 1:
             count = by_count.get(instr, _OTHER)[scope]
-            out.append([instr, scope, ms, sub[scope], count, sub["phases"]])
+            out.append([instr, scope, ms, path(sub[scope]), path(count), sub["phases"]])
     return sorted(out, key=lambda r: -r[2])
-
-
-def load_spans(trace_dir: str) -> list:
-    """The ``runtime.*`` events of every ``/host:CPU`` line of the trace
-    under ``trace_dir``, as ``[name, start_ns, dur_ns]``."""
-    from jax.profiler import ProfileData
-
-    (path,) = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
-    spans = []
-    for plane in ProfileData.from_file(path).planes:
-        if plane.name == "/host:CPU":
-            for line in plane.lines:
-                for e in line.events:
-                    if e.name.startswith(SPAN_PREFIX):
-                        spans.append([e.name, e.start_ns, e.duration_ns])
-    return sorted(spans, key=lambda e: e[1])
-
-
-def span_ms(spans: list, window, steps: int) -> dict[str, float]:
-    """Milliseconds per step of each span within the window, and the
-    number of spans per step."""
-    ms: collections.Counter = collections.Counter()
-    count = 0
-    for name, a, b in tr._clip(spans, *window):
-        ms[name] += (b - a) / 1e6 / steps
-        count += 1
-    return {**dict(sorted(ms.items())), "spans_per_step": count / steps}
-
-
-def _doing(spans: list, host: list, t: float) -> str:
-    """The innermost ``runtime.*`` span that holds time t, else the
-    innermost main-thread span (``chipbench.trace``'s rule)."""
-    held = [(dur, name) for name, start, dur in spans if start <= t <= start + dur]
-    return min(held)[1] if held else tr._host_doing(host, t)
-
-
-def idle_gaps(trace: dict, spans: list) -> list:
-    """The device's idle gaps in the window, named by ``_doing``: the ten
-    largest names' seconds, as ``reduce_trace``'s ``idle_gaps`` gives them."""
-    lo, hi = trace["window"]
-    host = sorted(trace.get("host", []), key=lambda e: e[1])
-    gaps = []
-    for dev in trace["devices"].values():
-        ops = tr._clip(tr._innermost(dev["ops"]), lo, hi)
-        busy = tr._union([(a, b) for _, a, b in ops])
-        edges = [lo] + [x for iv in busy for x in iv] + [hi]
-        for a, b in zip(edges[0::2], edges[1::2]):
-            if b > a:
-                gaps.append((b - a, a, b))
-    gaps.sort(reverse=True)
-    names: collections.Counter = collections.Counter()
-    n = len(trace["devices"])
-    for length, a, b in gaps[:200]:  # the rest are gaps between ops
-        names[_doing(spans, host, (a + b) / 2)] += length / n
-    return [[k, v / 1e9] for k, v in names.most_common(10)]
 
 
 def strip_metadata(hlo_text: str) -> str:
@@ -278,52 +230,24 @@ def span_cost_us(n: int = 20000) -> dict[str, float]:
 def measure(spec: dict, *, seed: int, seconds: float, require_tpu=True):
     """One traced run of the cell ``spec`` (``chipbench.run.run_cell``):
     its result line with a ``splits`` object added, and what it read: the
-    compiled step (``hlo``), the trace in plain form and its spans."""
-    import jax
-
+    compiled step (``hlo``) and the trace in plain form, with its spans."""
     from chipbench import run
 
-    # the compile cache's key leaves source metadata out by default, so a
-    # step compiled by another commit would come back with its op_names
-    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     seen: dict = {}
-    load, scope_map = tr.load_xplane, tr.scope_map
-
-    def load_xplane(trace_dir, window_name):
-        # the step is compiled by now: the reference's programs that follow
-        # may come from the cache as they are
-        jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
-        seen["spans"] = load_spans(trace_dir)
-        seen["trace"] = load(trace_dir, window_name)
-        return seen["trace"]
-
-    def see_scopes(hlo_text):
-        seen["hlo"] = hlo_text
-        return scope_map(hlo_text)
-
-    # run_cell reads the trace through these two; they are put back after
-    tr.load_xplane, tr.scope_map = load_xplane, see_scopes
-    try:
-        result = run.run_cell(
-            spec,
-            seed=seed,
-            seconds=seconds,
-            trace=True,
-            t_start=run.T_START,
-            require_tpu=require_tpu,
-        )
-    finally:
-        tr.load_xplane, tr.scope_map = load, scope_map
-        jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
-    trace, spans, steps = seen["trace"], seen["spans"], result["attempted"]
-    ops = op_ms(trace, scope_map(seen["hlo"]), steps)
+    result = run.run_cell(
+        spec,
+        seed=seed,
+        seconds=seconds,
+        trace=True,
+        t_start=run.T_START,
+        require_tpu=require_tpu,
+        seen=seen,
+    )
+    ops = op_ms(seen["trace"], tr.scope_map(seen["hlo"]), result["attempted"])
     subs, by_count = sub_map(seen["hlo"]), sub_map(seen["hlo"], by_bytes=False)
     result["splits"] = {
-        **split_trace(ops, subs),
         "by_count": split_trace(ops, by_count),
         "mixed_ops": mixed_ops(ops, subs, by_count),
-        "span_ms": span_ms(spans, trace["window"], steps),
-        "idle_gaps": idle_gaps(trace, spans),
         "span_cost_us": span_cost_us(),
     }
     return result, seen
@@ -354,7 +278,7 @@ def main(argv: list[str] | None = None) -> int:
         with gzip.open(out / "step.hlo.txt.gz", "wt") as f:
             f.write(seen["hlo"])
         with gzip.open(out / "trace.json.gz", "wt") as f:
-            json.dump({**seen["trace"], "spans": seen["spans"]}, f)
+            json.dump(seen["trace"], f)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -139,11 +139,13 @@ def test_layers_share_one_spelling():
 def test_model_flops_per_token_against_hand_counts():
     mamba = _config("mamba2-370m")["model"]
     # per layer: in_proj 2*1024*4384, out_proj 2*2048*1024, conv 2*4*2304,
-    # SSD 2*32*(256*128 + 256*64 + 2*64*128); head 2*1024*50280; x3
-    layer = 2 * 1024 * 4384 + 2 * 2048 * 1024 + 2 * 4 * 2304 + 2 * 32 * 65536
+    # SSD 2*(256*128 + 32*(256*64 + 2*64*128)), C·Bᵀ once for its one B/C
+    # group and the rest per head; head 2*1024*50280; x3
+    ssd = 2 * (256 * 128 + 32 * (256 * 64 + 2 * 64 * 128))
+    layer = 2 * 1024 * 4384 + 2 * 2048 * 1024 + 2 * 4 * 2304 + ssd
     hand = 3 * (48 * layer + 2 * 1024 * 50280)
     assert flops.model_flops_per_token(mamba, 2048) == hand
-    assert hand == pytest.approx(2.81e9, rel=1e-3)
+    assert hand == pytest.approx(2.52e9, rel=1e-3)
     nemo = dict(_config("mistral-nemo-12b-l2")["model"], repeats=4)
     # per layer: q, k, v, o projections, QK^T and PV over S/2 = 2048 keys,
     # SwiGLU 3 * 2*5120*14336; head 2*5120*16384; x3

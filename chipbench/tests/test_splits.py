@@ -84,42 +84,71 @@ def _trace():
     }
 
 
+def _top(parts: dict) -> float:
+    """The sum of a bucket's outermost parts and ``other``."""
+    return sum(ms for name, ms in parts.items() if "/" not in name)
+
+
 def test_parts_of_a_hand_made_trace_add_up_to_their_buckets():
+    """The open tag rule gives the parts the fixed tables of tags gave
+    (``mixer``, ``mlp``, ``head``, ``optimizer``; ``orth``, ``power``,
+    ``codec``), under the tags' own names."""
     scopes = tr.scope_map(HLO)
     whole = tr.reduce_trace(_trace(), scopes, steps=1)
     ops = splits.op_ms(_trace(), scopes, steps=1)
     got = splits.split_trace(ops, splits.sub_map(HLO))
     model, comp = got["model"], got["compress"]
-    assert model["head"] == pytest.approx(10e-6)
-    assert model["mixer"] == pytest.approx(50e-6)
-    assert model["mlp"] == pytest.approx(30e-6)  # 2 mlp, 1 mixer instruction
+    assert model["model.head"] == pytest.approx(10e-6)
+    assert model["model.mixer"] == pytest.approx(50e-6)
+    assert model["model.mlp"] == pytest.approx(30e-6)  # 2 mlp, 1 mixer instruction
     assert model["other"] == pytest.approx(5e-6)
-    assert model["optimizer"] == 0.0  # fused into the compressor's pass
-    assert model["bwd"] == pytest.approx(30e-6)
-    assert model["bwd_remat"] == 0.0  # 1 of its 3 instructions, not most
-    assert comp["orth"] == pytest.approx(5e-6)
-    assert comp["codec"] == pytest.approx(15e-6)  # the gather is not in it
-    assert comp["power"] == pytest.approx(25e-6)
-    parts = [p for _, p in splits.MODEL_TAGS] + ["other"]
-    assert sum(model[p] for p in parts) == pytest.approx(whole["model_ms"])
-    assert sum(comp.values()) == pytest.approx(whole["compress_ms"])
-    assert model["bwd"] <= whole["model_ms"]
+    assert "train.optimizer" not in model  # fused into the compressor's pass
+    assert got["bwd"] == pytest.approx(30e-6)
+    assert got["bwd_remat"] == 0.0  # 1 of its 3 instructions, not most
+    assert comp["lowrank.orth"] == pytest.approx(5e-6)
+    assert comp["codec.encode"] == pytest.approx(15e-6)  # the gather is not in it
+    assert "codec.decode" not in comp
+    assert comp["lowrank.power"] == pytest.approx(25e-6)
+    assert _top(model) == pytest.approx(whole["model_ms"])
+    assert _top(comp) == pytest.approx(whole["compress_ms"])
+    assert got["bwd"] <= whole["model_ms"]
     by_count = splits.split_trace(ops, splits.sub_map(HLO, by_bytes=False))
-    assert by_count["compress"]["codec"] == pytest.approx(40e-6)  # 2 decodes
-    assert by_count["compress"]["power"] == 0.0
-    assert sum(by_count["compress"].values()) == pytest.approx(whole["compress_ms"])
+    assert by_count["compress"]["codec.decode"] == pytest.approx(25e-6)  # 2 decodes
+    assert "lowrank.power" not in by_count["compress"]
+    assert _top(by_count["compress"]) == pytest.approx(whole["compress_ms"])
+
+
+def test_tags_are_read_through_jax_wrappers():
+    assert splits.tags("jit(f)/transpose(jvp(model.head))/mul") == ("model.head",)
+    assert splits.tags("jit(s)/comp.lq_sgd.eager/codec.decode/mul") == (
+        "codec.decode",
+    )
+    remat = "jit(s)/transpose(jvp())/checkpoint/rematted_computation/"
+    assert splits.tags(remat + "model.mixer/ssd.cb/dot_general") == (
+        "model.mixer",
+        "ssd.cb",
+    )
+    assert splits.tags("jit(s)/jvp(model.mlp)/while/body/model.mlp/add") == (
+        "model.mlp",
+    )
+    assert splits.tags("jit(step_fn)/vmap(jit(norm))/lnm,lnr->lmr") == ()
 
 
 def test_sub_map_votes_and_passes():
     subs = splits.sub_map(HLO)
-    assert subs["fusion.7"]["compress"] == "power"  # 64 of 68 result words
-    assert subs["fusion.7"]["model"] == "optimizer"
-    assert subs["fusion.7"]["phases"] == ["optimizer", "codec", "power"]
-    assert splits.sub_map(HLO, by_bytes=False)["fusion.7"]["compress"] == "codec"
+    assert subs["fusion.7"]["compress"] == ("lowrank.power",)  # 64 of 68 words
+    assert subs["fusion.7"]["model"] == ("train.optimizer",)
+    assert subs["fusion.7"]["phases"] == [
+        "codec.decode",
+        "lowrank.power",
+        "train.optimizer",
+    ]
+    by_count = splits.sub_map(HLO, by_bytes=False)
+    assert by_count["fusion.7"]["compress"] == ("codec.decode",)
     assert subs["fusion.3"]["backward"] and not subs["fusion.3"]["remat"]
     assert not subs["mix.2"]["backward"]
-    assert subs["norm.8"]["model"] == "other"
-    assert subs["all-gather.6"]["compress"] == "other"
+    assert subs["norm.8"]["model"] is None
+    assert subs["all-gather.6"]["compress"] is None
 
 
 def test_mixed_ops_name_where_each_was_counted():
@@ -128,34 +157,42 @@ def test_mixed_ops_name_where_each_was_counted():
     by_count = splits.sub_map(HLO, by_bytes=False)
     got = splits.mixed_ops(ops, splits.sub_map(HLO), by_count, min_ms=0.0)
     assert [r[0] for r in got] == ["fusion.3", "fusion.7"]  # longest first
-    assert got[0][1:5] == ["model", pytest.approx(30e-6), "mlp", "mlp"]
+    assert got[0][1:5] == ["model", pytest.approx(30e-6), "model.mlp", "model.mlp"]
     instr, bucket, ms, part, count, phases = got[1]
-    assert (bucket, part, count) == ("compress", "power", "codec")
-    assert ms == pytest.approx(25e-6) and phases == ["optimizer", "codec", "power"]
+    assert (bucket, part, count) == ("compress", "lowrank.power", "codec.decode")
+    assert ms == pytest.approx(25e-6)
+    assert phases == ["codec.decode", "lowrank.power", "train.optimizer"]
     assert splits.mixed_ops(ops, splits.sub_map(HLO), by_count) == []  # < 1 ms
 
 
 def test_idle_gaps_take_the_runtime_span_that_holds_them():
-    gaps = dict(splits.idle_gaps(_trace(), SPANS))
+    scopes = tr.scope_map(HLO)
+    spanned = {**_trace(), "spans": SPANS}
+    gaps = dict(tr.reduce_trace(spanned, scopes, steps=1)["idle_gaps"])
     assert gaps["runtime.dispatch"] == pytest.approx(80e-9)
     # no runtime span holds the last gap: the main thread's innermost span
     assert gaps["$api.py:2894 device_get"] == pytest.approx(50e-9)
-    assert splits.idle_gaps(_trace(), []) == tr.reduce_trace(
-        _trace(), tr.scope_map(HLO), steps=1
-    )["idle_gaps"]
+    # without spans the main thread's innermost span names every gap
+    plain = dict(tr.reduce_trace(_trace(), scopes, steps=1)["idle_gaps"])
+    assert plain["$runtime.py:300 run"] == pytest.approx(80e-9)
+    assert "runtime.dispatch" not in plain
 
 
 def test_span_ms_per_step_within_the_window():
-    got = splits.span_ms(SPANS + [["runtime.drain", 280, 50]], [0, 300], steps=2)
-    assert got["runtime.dispatch"] == pytest.approx(45e-6)
-    assert got["runtime.drain"] == pytest.approx(10e-6)  # clipped at 300
-    assert got["spans_per_step"] == 2.0
+    from chipbench.run import span_ms
+
+    before = {"runtime.dispatch": 1.0, "runtime.drain": 0.5}
+    after = {"runtime.dispatch": 1.09, "runtime.drain": 0.52, "runtime.x": 0.004}
+    got = span_ms(before, after, steps=2)
+    assert got["runtime.dispatch"] == pytest.approx(45.0)
+    assert got["runtime.drain"] == pytest.approx(10.0)
+    assert got["runtime.x"] == pytest.approx(2.0)  # a span new in the window
 
 
 def test_recorded_chip_trace_reads_as_before():
     """The recorded trace of a program without the tags: its expectation
-    holds unchanged, all of each bucket is ``other``, and without spans the
-    idle gaps are named as ``reduce_trace`` names them."""
+    holds unchanged and, as under the fixed tables of tags, no op has a
+    part and all of each bucket is ``other``."""
     trace = json.loads((DATA / "trace_m1.json").read_text())
     hlo = (DATA / "step_m1.hlo.txt").read_text()
     scopes = tr.scope_map(hlo)
@@ -164,9 +201,12 @@ def test_recorded_chip_trace_reads_as_before():
     for key, value in want.items():
         assert whole[key] == pytest.approx(value, rel=1e-9), key
     got = splits.split_trace(splits.op_ms(trace, scopes, 1), splits.sub_map(hlo))
-    assert got["model"]["other"] == pytest.approx(whole["model_ms"], rel=1e-9)
-    assert got["compress"]["other"] == pytest.approx(whole["compress_ms"], rel=1e-9)
-    assert splits.idle_gaps(trace, []) == whole["idle_gaps"]
+    assert got["model"] == {"other": pytest.approx(whole["model_ms"], rel=1e-9)}
+    assert got["compress"] == {"other": pytest.approx(whole["compress_ms"], rel=1e-9)}
+    assert 0 < got["bwd"] <= whole["model_ms"]  # the slice's transpose( ops
+    assert whole["idle_gaps"] == tr.reduce_trace({**trace, "spans": []}, scopes, 1)[
+        "idle_gaps"
+    ]
 
 
 def test_strip_metadata_keeps_the_program():
@@ -193,14 +233,14 @@ def test_load_spans_reads_every_host_thread(tmp_path):
         t.start()
         t.join(timeout=30)
     assert not t.is_alive()
-    names = [name for name, _, _ in splits.load_spans(str(tmp_path))]
+    names = [name for name, _, _ in tr._spans(tr._xplane(str(tmp_path)).planes)]
     assert sorted(names) == ["runtime.batch_build", "runtime.dispatch"]
 
 
 def test_main_writes_the_step_and_trace(tmp_path, monkeypatch, capsys):
     import gzip
 
-    seen = {"hlo": HLO, "trace": _trace(), "spans": SPANS}
+    seen = {"hlo": HLO, "trace": {**_trace(), "spans": SPANS}}
     monkeypatch.setattr(splits, "measure", lambda spec, **kw: ({"ok": 1}, seen))
     cell = json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"][0]
     argv = ["--workload", cell["name"], "--seed", "5", "--out", str(tmp_path)]

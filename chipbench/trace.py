@@ -5,7 +5,8 @@ A trace is first brought to one plain form, which tests can hold as JSON:
     {"window": [start_ns, end_ns],          # the host's span of the window
      "devices": {"0": {"ops": [[name, start_ns, dur_ns], ...],
                        "async": [[name, start_ns, dur_ns], ...]}, ...},
-     "host": [[name, start_ns, dur_ns], ...]}  # the main thread's spans
+     "host": [[name, start_ns, dur_ns], ...],  # the main thread's spans
+     "spans": [[name, start_ns, dur_ns], ...]}  # runtime.* spans, any thread
 
 ``ops`` is the device's "XLA Ops" line: one event per executed HLO
 instruction, run one after another on the TensorCore. ``async`` is its
@@ -20,6 +21,9 @@ that holds any instruction of the compressor is the compressor's; any other
 takes its own tags, or without them the tags most of its fused instructions
 carry. Events that hold other events (a ``while`` around its body's ops)
 are left out: only the innermost ops count as busy or as a layer's time.
+An idle gap of the device is named after the innermost ``runtime.*`` span
+(``repro.train.runtime.AsyncRunner``'s, on any host thread) that holds its
+midpoint, else after the innermost main-thread span.
 """
 
 from __future__ import annotations
@@ -52,6 +56,7 @@ COLLECTIVES = (
     "all-to-all",
     "collective-broadcast",
 )
+SPAN_PREFIX = "runtime."
 _SUFFIX = re.compile(r"\.\d+$")
 
 
@@ -181,6 +186,7 @@ def reduce_trace(trace: dict, scopes: dict[str, tuple[str, str]], steps: int):
     op_groups: collections.Counter = collections.Counter()
     gaps_all = []
     host = sorted(trace.get("host", []), key=lambda e: e[1])
+    spans = trace.get("spans", [])
     for dev in trace["devices"].values():
         ops = _clip(_innermost(dev["ops"]), lo, hi)
         asyncs = _clip(dev.get("async", []), lo, hi)
@@ -222,7 +228,7 @@ def reduce_trace(trace: dict, scopes: dict[str, tuple[str, str]], steps: int):
     gaps_all.sort(reverse=True)
     gap_names: collections.Counter = collections.Counter()
     for length, a, b in gaps_all[:200]:  # the rest are gaps between ops
-        gap_names[_host_doing(host, (a + b) / 2)] += length / n
+        gap_names[_host_doing(host, (a + b) / 2, spans)] += length / n
     return {
         "busy_s": busy_ns / 1e9,
         "window_s": window / 1e9,
@@ -238,8 +244,12 @@ def reduce_trace(trace: dict, scopes: dict[str, tuple[str, str]], steps: int):
     }
 
 
-def _host_doing(host: list, t: float) -> str:
-    """The innermost main-thread span that holds time t."""
+def _host_doing(host: list, t: float, spans: list = ()) -> str:
+    """The innermost ``runtime.*`` span that holds time t, else the
+    innermost main-thread span that does."""
+    held = [(dur, name) for name, start, dur in spans if start <= t <= start + dur]
+    if held:
+        return min(held)[1]
     best = None
     for name, start, dur in host:
         if start > t:
@@ -249,12 +259,28 @@ def _host_doing(host: list, t: float) -> str:
     return best[0] if best else "host: no span"
 
 
-def load_xplane(trace_dir: str, window_name: str) -> dict:
-    """Read the ``.xplane.pb`` under ``trace_dir`` into the plain form."""
+def _spans(planes) -> list:
+    """The ``runtime.*`` events of every ``/host:CPU`` line, by start."""
+    spans = []
+    for plane in planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(SPAN_PREFIX):
+                        spans.append([e.name, e.start_ns, e.duration_ns])
+    return sorted(spans, key=lambda e: e[1])
+
+
+def _xplane(trace_dir: str):
     from jax.profiler import ProfileData
 
     (path,) = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
-    pd = ProfileData.from_file(path)
+    return ProfileData.from_file(path)
+
+
+def load_xplane(trace_dir: str, window_name: str) -> dict:
+    """Read the ``.xplane.pb`` under ``trace_dir`` into the plain form."""
+    pd = _xplane(trace_dir)
     devices: dict[str, dict] = {}
     host: list = []
     window = None
@@ -276,4 +302,5 @@ def load_xplane(trace_dir: str, window_name: str) -> dict:
                         host.append([e.name, e.start_ns, e.duration_ns])
     if window is None or not devices:
         raise RuntimeError(f"trace in {trace_dir} has no window span or no TPU plane")
-    return {"window": window, "devices": devices, "host": host}
+    spans = _spans(pd.planes)
+    return {"window": window, "devices": devices, "host": host, "spans": spans}
